@@ -42,6 +42,10 @@ sizes, skips the JSON, and exits non-zero on a non-finite result or
 decoupled-under-ingest p99 > 3x idle p99 (the ``make bench-smoke``
 gate).
 
+CPU only: the tenant-scaling children force host devices, and a child
+cannot reach a chip its parent holds, so this is not the chip path
+(``chip_smoke.py`` phase B drives the decoupled service on the chip).
+
     PYTHONPATH=src python -m benchmarks.bench_serving [--smoke]
 """
 from __future__ import annotations

@@ -23,6 +23,11 @@ Emits ``BENCH_sharded.json`` at the repo root.  ``--smoke`` runs toy
 sizes, skips the JSON, and exits non-zero on non-finite output (the
 ``make bench-smoke`` gate).
 
+CPU only: each child process forces P host devices, and a child cannot
+reach a chip its parent holds, so this is not the chip path.  The
+row-sharded window step runs on four TPU chips through
+``python chip_smoke.py --four-chips``.
+
     PYTHONPATH=src python -m benchmarks.bench_sharded [--smoke]
 """
 from __future__ import annotations
@@ -46,6 +51,7 @@ def _worker(P: int, smoke: bool) -> dict:
     import jax.numpy as jnp
 
     from repro.core import distributed as dkpca, engine as eng, rankone
+    from repro.distributed.sharding import make_mesh
 
     assert jax.device_count() >= P, (jax.device_count(), P)
     if smoke:
@@ -69,7 +75,7 @@ def _worker(P: int, smoke: bool) -> dict:
         v[:m] = np.random.default_rng(seed).normal(size=m)
         return jnp.asarray(v)
 
-    mesh = jax.make_mesh((P,), ("data",))
+    mesh = make_mesh((P,), ("data",))
     mj = jnp.int32(m)
 
     def _median_time(fn, args_of_round) -> float:

@@ -27,6 +27,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import bench_multitenant, bench_update_scaling, \
         fig1_drift, fig2_nystrom, flops_table, roofline, timing
 

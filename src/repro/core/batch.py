@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kernels_fn as kf
+from repro.core.precision import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -60,12 +61,15 @@ def rotated_eigh_step(L: Array, U: Array, Kprev: Array, Knew: Array
     m = L.shape[0]
     Kp_new = kf.center_gram(Knew)
     # Q = blockdiag(U, 1) spans R^{m+1}; project, eigh, rotate.
-    Kp_old = (U * L[None, :]) @ U.T
+    Kp_old = jnp.matmul(U * L[None, :], U.T, precision=MATMUL_PRECISION)
     delta = Kp_new - jnp.pad(Kp_old, ((0, 1), (0, 1)))
     Q = jnp.pad(U, ((0, 1), (0, 1))).at[m, m].set(1.0)
-    small = jnp.diag(jnp.pad(L, (0, 1))) + Q.T @ delta @ Q
+    small = (jnp.diag(jnp.pad(L, (0, 1)))
+             + jnp.matmul(jnp.matmul(Q.T, delta, precision=MATMUL_PRECISION),
+                          Q, precision=MATMUL_PRECISION))
     lam, V = jnp.linalg.eigh(small)
-    return lam, Q @ V   # one (m+1)x(m+1) matmul — the baseline's hot spot
+    # one (m+1)x(m+1) matmul — the baseline's hot spot
+    return lam, jnp.matmul(Q, V, precision=MATMUL_PRECISION)
 
 
 # Alias: the unadjusted-case baseline of Hoegaerts et al. (2007) performs the

@@ -66,7 +66,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import downdate as dd
 from repro.core import engine as eng
 from repro.core import kernels_fn as kf, rankone
-from repro.distributed.sharding import shard_map as _shard_map
+from repro.core.precision import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -91,7 +91,8 @@ def _rank_one_update_sharded(L, U_local, v_local, sigma, m, *,
     the offset stays the global one).
     """
     r0 = jax.lax.axis_index(axis) * (rows_full or U_local.shape[0])
-    z = jax.lax.psum(U_local.T @ v_local, axis)
+    z = jax.lax.psum(
+        jnp.matmul(U_local.T, v_local, precision=MATMUL_PRECISION), axis)
     return rankone._update_body(L, U_local, v_local, sigma, m,
                                 matmul=plan.inner_matmul, z=z, row_offset=r0,
                                 **_solve_kwargs(plan, L.dtype))
@@ -122,7 +123,8 @@ def _rank_one_update_pair_sharded(L, U_local, v1_local, sigma1, v2_local,
     kw = _solve_kwargs(plan, L.dtype)
     if Z is None:
         Z = jax.lax.psum(
-            U_local.T @ jnp.stack([v1_local, v2_local], axis=1), axis)
+            jnp.matmul(U_local.T, jnp.stack([v1_local, v2_local], axis=1),
+                       precision=MATMUL_PRECISION), axis)
     pf = rankone._pair_solve(L, Z[:, 0], sigma1, Z[:, 1], sigma2, m, **kw)
 
     def _fused(U):
@@ -146,7 +148,8 @@ def _rank_one_update_pair_sharded(L, U_local, v1_local, sigma1, v2_local,
     # Collective balance: psum 2 is unconditional.  Merge-free steady
     # state: U1 == U_local, so this recomputes Z[:, 1] redundantly — the
     # O(M) price of a deadlock-free fallback.
-    z2 = jax.lax.psum(U1.T @ v2_local, axis)
+    z2 = jax.lax.psum(
+        jnp.matmul(U1.T, v2_local, precision=MATMUL_PRECISION), axis)
 
     def _seq2(U):
         return rankone._update_body(L1, U, v2_local, sigma2, m, z=z2,
@@ -228,7 +231,7 @@ def make_sharded_update(mesh, *, axis: str = "data",
         body = fixed_body if Mb is None else sliced_body(Mb)
         # jit the shard_map so repeated eager calls hit the compile cache
         # (bare shard_map re-traces per call).
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(axis, None), P(axis), P(), P()),
             out_specs=(P(), P(axis, None)),
@@ -271,7 +274,7 @@ def make_sharded_update_pair(mesh, *, axis: str = "data",
 
     def build(Mb: int | None):
         body = fixed_body if Mb is None else sliced_pair_body(Mb)
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(axis, None), P(axis), P(), P(axis), P(), P()),
             out_specs=(P(), P(axis, None)),
@@ -321,7 +324,8 @@ def _downdate_sharded(L, U_local, a, k_new, m, *, axis: str,
     # (``downdate.contract_rows`` — the row block passes its global row
     # indices so the forced identity pair lands on the owner shard).
     eq_local = (local_idx == q).astype(dtype)
-    w = jax.lax.psum(U_local.T @ eq_local, axis)        # global row q of U
+    w = jax.lax.psum(                                   # global row q of U
+        jnp.matmul(U_local.T, eq_local, precision=MATMUL_PRECISION), axis)
     w = jnp.where(rankone.active_mask(M, m), w, 0.0)
     return dd.contract_rows(L, U_local, w, m, row_ids=local_idx)
 
@@ -359,7 +363,7 @@ def make_sharded_downdate(mesh, *, axis: str = "data",
 
     def build(Mb: int | None):
         body = fixed_body if Mb is None else sliced_body(Mb)
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(axis, None), P(), P(), P()),
             out_specs=(P(), P(axis, None), P()),
@@ -397,7 +401,8 @@ def _permute_rows_sharded(rows_block, i, m, *, axis: str, nshards: int,
     shifted = jnp.concatenate([rows_block[1:], nbr[None]], axis=0)
     # (c) global row i, replicated to every device.
     sel = (gids == i).astype(rows_block.dtype)
-    row_i = jax.lax.psum(sel @ rows_block, axis)
+    row_i = jax.lax.psum(
+        jnp.matmul(sel, rows_block, precision=MATMUL_PRECISION), axis)
     keep = (gids < i) | (gids >= m)
     last = gids == (m - 1)
     return jnp.where(keep[:, None], rows_block,
@@ -445,7 +450,7 @@ def make_sharded_evict(mesh, *, axis: str = "data",
 
     def build(Mb: int | None):
         body = fixed_body if Mb is None else sliced_body(Mb)
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(axis, None), P(), P(), P(), P()),
             out_specs=(P(), P(axis, None), P()),
@@ -690,7 +695,7 @@ def make_sharded_window_block(mesh, spec: kf.KernelSpec, *,
 
     def build(Mb: int | None):
         body = fixed_body if Mb is None else sliced_body(Mb)
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(axis, None), P(), P(), P(), P(), P()),
             out_specs=(P(), P(axis, None), P(), P(), P()),
@@ -744,7 +749,7 @@ def make_sharded_expand(mesh, *, axis: str = "data"):
         perm = jnp.argsort(L)
         return L[perm], U_local[:, perm], m_new
 
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(axis, None), P(), P()),
         out_specs=(P(), P(axis, None), P()),
@@ -758,8 +763,9 @@ def sharded_gram_row(mesh, spec: kf.KernelSpec, *, axis: str = "data"):
     def body(X_local, x_new):
         return kf.kernel_row(x_new, X_local, spec=spec)
 
-    return jax.jit(_shard_map(body, mesh=mesh, in_specs=(P(axis, None), P()),
-                              out_specs=P(axis), check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(axis, None), P()),
+                                 out_specs=P(axis), check_vma=False))
 
 
 # ------------------------------------------------ tenant x row 2-D mesh --
@@ -790,7 +796,8 @@ def make_tenant_mesh(p_tenant: int, p_rows: int, *, devices=None):
     if devs.size < need:
         raise ValueError(f"mesh needs {need} devices, have {devs.size}")
     return jax.sharding.Mesh(devs[:need].reshape(p_tenant, p_rows),
-                             ("tenant", "data"))
+                             ("tenant", "data"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def make_tenant_update_pair(mesh, *, tenant_axis: str = "tenant",
@@ -837,7 +844,7 @@ def make_tenant_update_pair(mesh, *, tenant_axis: str = "tenant",
 
     def build(Mb: int | None):
         body = fixed_body if Mb is None else sliced_body(Mb)
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(tenant_axis), P(tenant_axis, axis),
                       P(tenant_axis, axis), P(tenant_axis),
@@ -883,7 +890,7 @@ def make_tenant_query(mesh, spec: kf.KernelSpec, *,
             lambda s, x: serving.query(s, x, spec=spec, plan=plan))(snaps,
                                                                     xq)
 
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(tenant_axis), P(tenant_axis)),
         out_specs=P(tenant_axis), check_vma=False))
@@ -957,7 +964,7 @@ def make_rebalanced_update(mesh, *, axis: str = "data",
                                             jnp.zeros((), L.dtype))
                 return L_new, U_local.at[:, :Mb].set(newcols)
 
-            bal_cache[Mb] = jax.jit(_shard_map(
+            bal_cache[Mb] = jax.jit(jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(), P(axis, None), P(), P(), P()),
                 out_specs=(P(), P(axis, None)),

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 
 from repro.core import engine as eng
 from repro.core import kernels_fn as kf, rankone
+from repro.core.precision import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -55,9 +56,17 @@ def boundary_perm(i: Array, m: Array, M: int) -> Array:
     rows never move.  Pure function of (i, m), so callers maintaining
     side arrays (ages rings, Nyström Knm columns) apply the same order.
     """
-    idx = jnp.arange(M)
-    key = jnp.where(idx == i, (m - 1).astype(jnp.float64) + 0.5,
-                    idx.astype(jnp.float64))
+    return _move_key_order(i, m - 1, M)
+
+
+def _move_key_order(src: Array, dst: Array, M: int) -> Array:
+    """argsort order moving slot ``src`` to just after slot ``dst``
+    (src <= dst), everything else keeping its relative order.  Integer
+    keys 2·idx and 2·dst + 1 are exact in int32, where a float key
+    would silently round without x64."""
+    idx = jnp.arange(M, dtype=jnp.int32)
+    dst = jnp.asarray(dst, jnp.int32)
+    key = jnp.where(idx == src, 2 * dst + 1, 2 * idx)
     return jnp.argsort(key)
 
 
@@ -104,14 +113,12 @@ def contract_rows(L: Array, U: Array, w: Array, m: Array, *,
     u = w + sgn * jax.nn.one_hot(j_star, M, dtype=dtype)
     unorm2 = jnp.sum(u * u)
     coef = jnp.where(unorm2 > jnp.finfo(dtype).tiny, 2.0 / unorm2, 0.0)
-    U = U - coef * jnp.outer(U @ u, u)           # U @ H, rank-one apply
+    # U @ H, a rank-one apply
+    U = U - coef * jnp.outer(jnp.matmul(U, u, precision=MATMUL_PRECISION), u)
 
-    # Column j* -> position q; columns between shift left by one.  Keys
-    # mirror boundary_perm, on the column axis.
-    idx = jnp.arange(M)
-    key = jnp.where(idx == j_star, q.astype(jnp.float64) + 0.5,
-                    idx.astype(jnp.float64))
-    order = jnp.argsort(key)
+    # Column j* -> position q; columns between shift left by one (the
+    # order of boundary_perm, on the column axis).
+    order = _move_key_order(j_star, q, M)
     U = U[:, order]
     L = L[order]
 

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kernels_fn as kf, rankone
+from repro.core.precision import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -1375,7 +1376,8 @@ class Engine:
         dtype = kpca.L.dtype
         mask = rankone.active_mask(M, trunc.m)
         Lm = jnp.where(mask, trunc.L, 0.0)
-        Kc = ((trunc.U * Lm[None, :]) @ trunc.U.T)[:r, :r]
+        Kc = jnp.matmul(trunc.U * Lm[None, :], trunc.U.T,
+                        precision=MATMUL_PRECISION)[:r, :r]
         lam, vec = jnp.linalg.eigh(Kc)
         # The block has rank <= k: flush the r-k numerically-zero
         # eigenvalues to exact 0 so the Nyström pseudo-inverse consumers
@@ -1540,9 +1542,12 @@ class StreamBatch:
         self.cohorts = cohorts
         self.window = window
         self.n_tenants = int(x0.shape[0])
-        self._full = jax.vmap(
-            lambda x: inkpca.init_state(x, capacity, spec, adjusted=adjusted,
-                                        dtype=dtype))(x0)
+        # One host-side batch init per tenant (``inkpca.gram_eigh``), then
+        # stacked: a vmapped init would trace the eigh into a TPU program.
+        self._full = jax.tree.map(
+            lambda *leaves: jnp.stack(leaves),
+            *[inkpca.init_state(x, capacity, spec, adjusted=adjusted,
+                                dtype=dtype) for x in x0])
         self._sub = None          # bucket-resident working state ("max")
         self._Mb = capacity
         # Host-side upper bound on max_i m_i (exact while every step is

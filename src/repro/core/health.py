@@ -58,6 +58,7 @@ import jax.numpy as jnp
 from repro.core import engine as eng
 from repro.core import kernels_fn as kf
 from repro.core import rankone
+from repro.core.precision import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -186,7 +187,8 @@ def probe(state, hstate: HealthState, policy: HealthPolicy,
     mm = jnp.maximum(m, 1)
     idx = (hstate.probes * B + jnp.arange(B, dtype=jnp.int32)) % mm
     cols = jnp.take(U, idx, axis=1)                      # (M, B)
-    E = U.T @ cols - jax.nn.one_hot(idx, M, dtype=dtype).T
+    E = (jnp.matmul(U.T, cols, precision=MATMUL_PRECISION)
+         - jax.nn.one_hot(idx, M, dtype=dtype).T)
     orth = jnp.sqrt(jnp.max(jnp.sum(E * E, axis=0)))
     act = rankone.active_mask(M, m)
     Lact = jnp.where(act, L, 0.0)
@@ -381,7 +383,8 @@ def exact_orth_residual(state) -> float:
     if not bool(jnp.all(jnp.isfinite(U))):
         return float("inf")
     M = U.shape[0]
-    E = U.T @ U - jnp.eye(M, dtype=U.dtype)
+    E = (jnp.matmul(U.T, U, precision=MATMUL_PRECISION)
+         - jnp.eye(M, dtype=U.dtype))
     return float(jnp.sqrt(jnp.max(jnp.sum(E * E, axis=0))))
 
 
@@ -404,6 +407,8 @@ def resync(state, spec: kf.KernelSpec, adjusted: bool):
     matches a batch KPCA of the same points by construction.  Raises
     ``HealthError`` (restore rung) when the stored points are corrupt.
     """
+    from repro.core import inkpca
+
     m = int(state.m)
     M = state.L.shape[0]
     dtype = state.L.dtype
@@ -416,7 +421,7 @@ def resync(state, spec: kf.KernelSpec, adjusted: bool):
     S = jnp.sum(K0)
     K1 = jnp.sum(K0, axis=1)
     Keff = kf.center_gram(K0) if adjusted else K0
-    lam, vec = jnp.linalg.eigh(Keff)
+    lam, vec = inkpca.gram_eigh(Keff)
     L = jnp.zeros((M,), dtype).at[:m].set(lam.astype(dtype))
     U = jnp.eye(M, dtype=dtype).at[:m, :m].set(vec.astype(dtype))
     L = rankone.sentinelize(L, state.m, jnp.zeros((), dtype))

@@ -20,6 +20,7 @@ from typing import Literal, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import engine as eng
 from repro.core import kernels_fn as kf
@@ -47,6 +48,24 @@ class KPCAState(NamedTuple):
     X: Array
 
 
+def gram_eigh(K: Array) -> tuple[Array, Array]:
+    """Ascending eigendecomposition of a symmetric gram matrix for a
+    batch (re)initialisation, in K's dtype.
+
+    A concrete K is decomposed on the host by LAPACK in float64.  A TPU
+    has no native eigh: ``jnp.linalg.eigh`` lowers there to a QDWH
+    spectral divide-and-conquer whose compile takes minutes and tens of
+    GiB of host memory at the sizes a chip holds (compiling the M=2048
+    one for a v5e passes 8 GiB of host memory; M=8192 ran a 40 GiB host
+    out of memory), while the host decomposition of an M=8192 gram takes
+    seconds and is more accurate.  Traced callers (under jit or vmap)
+    keep ``jnp.linalg.eigh``."""
+    if isinstance(K, jax.core.Tracer):
+        return jnp.linalg.eigh(K)
+    lam, vec = np.linalg.eigh(np.asarray(K, np.float64))
+    return jnp.asarray(lam, K.dtype), jnp.asarray(vec, K.dtype)
+
+
 def init_state(x0: Array, capacity: int, spec: kf.KernelSpec,
                *, adjusted: bool, dtype=jnp.float32) -> KPCAState:
     """Batch-initialize from m0 >= 1 seed points (eigh of the small gram)."""
@@ -57,7 +76,7 @@ def init_state(x0: Array, capacity: int, spec: kf.KernelSpec,
     S = jnp.sum(K0)
     K1 = jnp.sum(K0, axis=1)
     Keff = kf.center_gram(K0) if adjusted else K0
-    lam, vec = jnp.linalg.eigh(Keff)
+    lam, vec = gram_eigh(Keff)
 
     M = capacity
     L = jnp.zeros((M,), dtype)

@@ -15,6 +15,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import MATMUL_PRECISION
+
 Array = jax.Array
 
 
@@ -36,7 +38,7 @@ def _sqdist(x: Array, y: Array) -> Array:
     """Pairwise squared euclidean distances, (n,d),(m,d) -> (n,m)."""
     xn = jnp.sum(x * x, axis=-1)[:, None]
     yn = jnp.sum(y * y, axis=-1)[None, :]
-    d2 = xn + yn - 2.0 * (x @ y.T)
+    d2 = xn + yn - 2.0 * jnp.matmul(x, y.T, precision=MATMUL_PRECISION)
     return jnp.maximum(d2, 0.0)
 
 
@@ -49,9 +51,10 @@ def gram_block(x: Array, y: Array, *, spec: KernelSpec) -> Array:
     if spec.name == "rbf":
         return spec.scale * jnp.exp(-_sqdist(x, y) / spec.sigma)
     if spec.name == "linear":
-        return spec.scale * (x @ y.T)
+        return spec.scale * jnp.matmul(x, y.T, precision=MATMUL_PRECISION)
     if spec.name == "poly":
-        return spec.scale * (x @ y.T + spec.coef0) ** spec.degree
+        xy = jnp.matmul(x, y.T, precision=MATMUL_PRECISION)
+        return spec.scale * (xy + spec.coef0) ** spec.degree
     if spec.name == "matern32":
         r = jnp.sqrt(_sqdist(x, y) + 1e-30)
         a = jnp.sqrt(3.0) * r / spec.sigma
@@ -96,4 +99,6 @@ def center_gram(K: Array) -> Array:
     """Mean-adjusted kernel matrix K' = (I-1)K(I-1), eq. (1) of the paper."""
     n = K.shape[0]
     one = jnp.full((n, n), 1.0 / n, K.dtype)
-    return K - one @ K - K @ one + one @ K @ one
+    oK = jnp.matmul(one, K, precision=MATMUL_PRECISION)
+    return (K - oK - jnp.matmul(K, one, precision=MATMUL_PRECISION)
+            + jnp.matmul(oK, one, precision=MATMUL_PRECISION))

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.core import engine as eng
 from repro.core import inkpca, kernels_fn as kf, rankone
+from repro.core.precision import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -56,9 +57,9 @@ def coefficients(state: KRRState, lam: float) -> Array:
     M = st.L.shape[0]
     mask = rankone.active_mask(M, st.m)
     y = jnp.where(mask, state.y, 0.0)
-    z = st.U.T @ y
+    z = jnp.matmul(st.U.T, y, precision=MATMUL_PRECISION)
     inv = jnp.where(mask, 1.0 / (st.L + lam), 0.0)
-    return st.U @ (inv * z)
+    return jnp.matmul(st.U, inv * z, precision=MATMUL_PRECISION)
 
 
 def predict(state: KRRState, x: Array, lam: float,
@@ -68,7 +69,8 @@ def predict(state: KRRState, x: Array, lam: float,
     alpha = coefficients(state, lam)
     K_x = kf.gram_block(x.astype(st.X.dtype), st.X, spec=spec)
     mask = rankone.active_mask(st.X.shape[0], st.m)
-    return (jnp.where(mask[None, :], K_x, 0.0) @ alpha)
+    return jnp.matmul(jnp.where(mask[None, :], K_x, 0.0), alpha,
+                      precision=MATMUL_PRECISION)
 
 
 def publish_predict(state: KRRState, lam: float, *,
@@ -118,4 +120,5 @@ def lam_safe_dot(state: KRRState, alpha: Array) -> Array:
     M = st.L.shape[0]
     mask = rankone.active_mask(M, st.m)
     lam_active = jnp.where(mask, st.L, 0.0)
-    return st.U @ (lam_active * (st.U.T @ alpha))
+    proj = jnp.matmul(st.U.T, alpha, precision=MATMUL_PRECISION)
+    return jnp.matmul(st.U, lam_active * proj, precision=MATMUL_PRECISION)

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 
 from repro.core import engine as eng
 from repro.core import inkpca, kernels_fn as kf, rankone
+from repro.core.precision import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -225,7 +226,7 @@ def admission_residual(state: NystromState, x: Array,
     st = state.kpca
     mask = rankone.active_mask(st.L.shape[0], st.m)
     b, k_xx = eng.masked_row(st, x, spec)
-    y = st.U.T @ b
+    y = jnp.matmul(st.U.T, b, precision=MATMUL_PRECISION)
     return k_xx - jnp.sum(_pinv_lam(st.L, mask) * y * y)
 
 
@@ -279,7 +280,8 @@ def trace_error(state: NystromState, spec: kf.KernelSpec,
             "x_all, a non-constant-diagonal kernel, and observed rows "
             "not covered by the stored landmarks — pass x_all")
     mask = rankone.active_mask(st.L.shape[0], st.m)
-    B = state.Knm @ jnp.where(mask[None, :], st.U, 0.0)
+    B = jnp.matmul(state.Knm, jnp.where(mask[None, :], st.U, 0.0),
+                   precision=MATMUL_PRECISION)
     diag_tilde = jnp.sum(B**2 * _pinv_lam(st.L, mask)[None, :], axis=1)
     return jnp.sum(diag_k - diag_tilde)
 
@@ -311,11 +313,12 @@ def admission_trace_delta(state: NystromState, x: Array,
                          "(grow_rows state or x_all)")
     mask = rankone.active_mask(st.L.shape[0], st.m)
     b, k_xx = eng.masked_row(st, x, spec)
-    y = st.U.T @ b
+    y = jnp.matmul(st.U.T, b, precision=MATMUL_PRECISION)
     alpha = _pinv_lam(st.L, mask) * y          # K_mm⁺ b in the eigenbasis
     delta_res = k_xx - jnp.sum(y * alpha)
     c = kf.kernel_row(x, x_rows.astype(st.L.dtype), spec=spec)
-    r = state.Knm @ (st.U @ alpha) - c
+    w = jnp.matmul(st.U, alpha, precision=MATMUL_PRECISION)
+    r = jnp.matmul(state.Knm, w, precision=MATMUL_PRECISION) - c
     tol = jnp.finfo(st.L.dtype).eps * jnp.maximum(k_xx, 1.0)
     delta = jnp.where(delta_res > tol,
                       jnp.sum(r * r) / jnp.maximum(delta_res, tol), 0.0)
@@ -344,9 +347,9 @@ def removal_trace_delta(state: NystromState, j: Array
     mask = rankone.active_mask(st.L.shape[0], st.m)
     pinv = _pinv_lam(st.L, mask)
     uj = st.U[j, :]
-    w = st.U @ (pinv * uj)
+    w = jnp.matmul(st.U, pinv * uj, precision=MATMUL_PRECISION)
     Wjj = jnp.sum(uj * uj * pinv)
-    t = state.Knm @ w
+    t = jnp.matmul(state.Knm, w, precision=MATMUL_PRECISION)
     safe = jnp.maximum(Wjj, jnp.finfo(st.L.dtype).tiny)
     return jnp.sum(t * t) / safe, Wjj
 
@@ -378,18 +381,20 @@ def swap_trace_delta(state: NystromState, j: Array, x: Array,
     tiny = jnp.finfo(dtype).tiny
 
     uj = st.U[j, :]
-    w = st.U @ (pinv * uj)                     # W e_j
+    w = jnp.matmul(st.U, pinv * uj, precision=MATMUL_PRECISION)  # W e_j
     Wjj = jnp.maximum(jnp.sum(uj * uj * pinv), tiny)
-    t = state.Knm @ w
+    t = jnp.matmul(state.Knm, w, precision=MATMUL_PRECISION)
     inc = jnp.sum(t * t) / Wjj
 
     b, k_xx = eng.masked_row(st, x, spec)
     bt = b.at[j].set(0.0)                      # row vs SURVIVING landmarks
-    Wb = st.U @ (pinv * (st.U.T @ bt))
-    Ab = Wb - w * (jnp.dot(w, bt) / Wjj)       # A b̃, A = W − w wᵀ/W_jj
-    delta_res = k_xx - jnp.dot(bt, Ab)
+    Wb = jnp.matmul(st.U, pinv * jnp.matmul(st.U.T, bt,
+                                            precision=MATMUL_PRECISION),
+                    precision=MATMUL_PRECISION)
+    Ab = Wb - w * (jnp.dot(w, bt, precision=MATMUL_PRECISION) / Wjj)  # A b̃
+    delta_res = k_xx - jnp.dot(bt, Ab, precision=MATMUL_PRECISION)
     c = kf.kernel_row(x, x_rows.astype(dtype), spec=spec)
-    r = state.Knm @ Ab - c
+    r = jnp.matmul(state.Knm, Ab, precision=MATMUL_PRECISION) - c
     tol = jnp.finfo(dtype).eps * jnp.maximum(k_xx, 1.0)
     dec = jnp.where(delta_res > tol,
                     jnp.sum(r * r) / jnp.maximum(delta_res, tol), 0.0)
@@ -595,7 +600,9 @@ def nystrom_eigpairs(state: NystromState, n: int) -> tuple[Array, Array]:
     mask = rankone.active_mask(M, st.m)
     mf = st.m.astype(st.L.dtype)
     lam_nys = jnp.where(mask, (n / mf) * st.L, 0.0)
-    U_nys = jnp.sqrt(mf / n) * (state.Knm @ (st.U * _pinv_lam(st.L, mask)[None, :]))
+    U_nys = jnp.sqrt(mf / n) * jnp.matmul(
+        state.Knm, st.U * _pinv_lam(st.L, mask)[None, :],
+        precision=MATMUL_PRECISION)
     U_nys = jnp.where(mask[None, :], U_nys, 0.0)
     return lam_nys, U_nys
 
@@ -625,7 +632,7 @@ def query_features(state: NystromState, xq: Array, n: int,
         kq = kf.gram_block(jnp.asarray(xq).astype(st.X.dtype), st.X,
                            spec=spec)
         kq = jnp.where(mask[None, :], kq, 0.0)
-        y = kq @ s_mat
+        y = jnp.matmul(kq, s_mat, precision=MATMUL_PRECISION)
     return jnp.sqrt(mf / n) * jnp.where(mask[None, :], y, 0.0)
 
 
@@ -662,12 +669,13 @@ def reconstruct_tilde(state: NystromState, *, use_pallas: bool = False) -> Array
     st = state.kpca
     M = st.L.shape[0]
     mask = rankone.active_mask(M, st.m)
-    B = state.Knm @ jnp.where(mask[None, :], st.U, 0.0)   # (n, M)
+    B = jnp.matmul(state.Knm, jnp.where(mask[None, :], st.U, 0.0),
+                   precision=MATMUL_PRECISION)   # (n, M)
     inv_lam = _pinv_lam(st.L, mask)
     if use_pallas:
         from repro.kernels.nystrom_recon import ops as _ops
         return _ops.scaled_gram(B, inv_lam)
-    return (B * inv_lam[None, :]) @ B.T
+    return jnp.matmul(B * inv_lam[None, :], B.T, precision=MATMUL_PRECISION)
 
 
 @dataclass

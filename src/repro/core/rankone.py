@@ -39,6 +39,8 @@ from typing import Literal, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import MATMUL_PRECISION
+
 Array = jax.Array
 
 # Margin multiplier used when regenerating sentinel eigenvalues.
@@ -70,23 +72,84 @@ def sentinelize(d: Array, m: Array, room: Array) -> Array:
     return jnp.where(mask, d, sent)
 
 
+def _safe_den(den: Array) -> Array:
+    """Pole distance with exact zeros (only possible for a root pinned on
+    its own deflated pole) nudged to the smallest normal number, sign
+    kept — a relative guard, so a genuinely tiny distance is kept."""
+    tiny = jnp.finfo(den.dtype).tiny
+    return jnp.where(jnp.abs(den) < tiny, jnp.where(den < 0, -tiny, tiny),
+                     den)
+
+
+def _log(r: Array) -> Array:
+    """Natural log of r > 0 to working accuracy on every backend.
+
+    The TPU's f32 ``log`` is off by hundreds of ulps (thousands near
+    r = 1), and the Gu–Eisenstat product below sums M of them, so the
+    eigenvectors would lose orthogonality at that rate.  Here only
+    exact bit operations and +, ×, ÷ are used: r = f·2^e with f in
+    [1/√2, √2) (``frexp``), and log r = e·ln 2 + 2·atanh(s),
+    s = (f - 1)/(f + 1), |s| < 0.172, by its odd series — each term
+    gains a factor 34, so six terms reach f32 resolution and twelve f64;
+    ln 2 is split in two so that e·ln 2 stays exact to working accuracy.
+    """
+    f, e = jnp.frexp(r)                                  # f in [1/2, 1)
+    low = f < 0.7071067811865476
+    f = jnp.where(low, 2.0 * f, f)
+    e = (e - low.astype(e.dtype)).astype(r.dtype)
+    s = (f - 1.0) / (f + 1.0)
+    s2 = s * s
+    n = 6 if jnp.dtype(r.dtype).itemsize <= 4 else 12
+    poly = jnp.zeros_like(s)
+    for k in range(n - 1, -1, -1):
+        poly = 1.0 / (2 * k + 1) + s2 * poly
+    ln2_hi, ln2_lo = 0.693145751953125, 1.4286068203094172e-06
+    return e * ln2_hi + (e * ln2_lo + 2.0 * s * poly)
+
+
+def _exp(h: Array) -> Array:
+    """exp h to working accuracy on every backend (the TPU's f32 ``exp``
+    is off by up to ~100 ulps): h = k·ln 2 + r with |r| ≤ ln2/2, exp r by
+    its Taylor series (eight terms for f32, fourteen for f64) and the
+    power of two applied exactly by ``ldexp``."""
+    k = jnp.round(h / 0.6931471805599453)
+    r = (h - k * 0.693145751953125) - k * 1.4286068203094172e-06
+    n = 8 if jnp.dtype(h.dtype).itemsize <= 4 else 14
+    p = jnp.ones_like(r)
+    for i in range(n - 1, 0, -1):
+        p = 1.0 + r * p / i
+    return jnp.ldexp(p, k.astype(jnp.int32))
+
+
 def _secular_bisect(d: Array, z2: Array, sigma: Array, iters: int,
-                    defl: Array | None = None) -> Array:
-    """All roots of 1 + sigma * sum_i z2_i/(d_i - t), sigma > 0, d ascending.
+                    defl: Array | None = None) -> tuple[Array, Array]:
+    """All roots of 1 + sigma * sum_i z2_i/(d_i - t), sigma > 0, d ascending,
+    each as ``org + tau``: ``org`` the pole nearer to the root, ``tau``
+    the root's offset from it.
 
     Root j lives in (d_j, next pole) for j < M-1 and (d_{M-1}, d_{M-1} +
-    sigma*sum(z2)) for the top root (paper eq. 5).  Fixed-iteration
-    bisection, fully vectorized over all M roots.
+    sigma*sum(z2)) for the top root (paper eq. 5).  A root can sit much
+    closer to a pole than the pole's own rounding unit (a small z_j moves
+    its eigenvalue by ~sigma·z_j²), so it is never formed as an absolute
+    value: the distances d_i - root = (d_i - org) - tau keep full relative
+    accuracy, which the Gu–Eisenstat z-recompute and the Cauchy
+    eigenvector factor both divide by (the LAPACK dlaed4 device of
+    solving in coordinates shifted to the nearer pole).  One evaluation
+    at the bracket's midpoint picks the nearer pole; ``iters`` geometric
+    bisection steps then shrink |tau| over [half·eps², half] (half the
+    bracket), so the offset converges in RELATIVE terms, ~32·2^-iters,
+    however close the root lies to its pole.  Fully vectorized over all
+    M roots.
 
     ``defl`` marks deflated poles (z_i == 0, Bunch §4): their eigenvalue
-    stays AT the pole, and the bracket of every other root skips over them
-    (the upper end is the next NON-deflated pole) — otherwise a root to the
-    right of a deflated pole is lost and the pole double-counted.
+    stays AT the pole (tau = 0), and the bracket of every other root skips
+    over them (the upper end is the next NON-deflated pole) — otherwise a
+    root to the right of a deflated pole is lost and the pole
+    double-counted.
     """
-    M = d.shape[0]
+    eps = _eps_for(d.dtype)
     znorm2 = jnp.sum(z2)
-    top = d[-1] + sigma * znorm2 + _eps_for(d.dtype)
-    lo = d
+    top = d[-1] + sigma * znorm2 + eps
     if defl is None:
         hi = jnp.concatenate([d[1:], top[None]])
     else:
@@ -95,24 +158,37 @@ def _secular_bisect(d: Array, z2: Array, sigma: Array, iters: int,
             [jax.lax.cummin(d_nd[::-1])[::-1][1:], jnp.asarray([jnp.inf],
                                                                d.dtype)])
         hi = jnp.where(jnp.isinf(nxt), top, nxt)
+    half = 0.5 * (hi - d)
 
-    def w_at(t: Array) -> Array:
-        # t: (M,) candidate per root; terms (M poles, M roots)
-        den = d[:, None] - t[None, :]
-        safe = jnp.where(den == 0.0, _eps_for(d.dtype), den)
-        return 1.0 + sigma * jnp.sum(z2[:, None] / safe, axis=0)
+    def w_at(D: Array, tau: Array) -> Array:
+        # D: (M poles, M roots) pole-to-origin distances, tau: (M,)
+        den = _safe_den(D - tau[None, :])
+        return 1.0 + sigma * jnp.sum(z2[:, None] / den, axis=0)
+
+    # w increases between poles: w > 0 at the midpoint puts the root in
+    # the left half, nearer d_j; otherwise nearer the right end.
+    left = w_at(d[:, None] - d[None, :], half) > 0.0
+    org = jnp.where(left, d, hi)
+    sgn = jnp.where(left, 1.0, -1.0).astype(d.dtype)
+    D = d[:, None] - org[None, :]
 
     def body(_, lohi):
-        lo, hi = lohi
-        mid = 0.5 * (lo + hi)
-        pos = w_at(mid) > 0.0  # w increasing between poles => root below mid
-        return jnp.where(pos, lo, mid), jnp.where(pos, mid, hi)
+        lo, up = lohi
+        # sqrt of each end: lo·up can fall below the smallest normal
+        # number, which a TPU flushes to zero.
+        mid = jnp.sqrt(lo) * jnp.sqrt(up)
+        # From the left pole the root moves right as |tau| grows, from
+        # the right one left: either way w > 0 means a smaller |tau| when
+        # the origin is the left pole and a larger one otherwise.
+        smaller = (w_at(D, sgn * mid) > 0.0) == left
+        return jnp.where(smaller, lo, mid), jnp.where(smaller, mid, up)
 
-    lo, hi = jax.lax.fori_loop(0, iters, body, (lo, hi))
-    roots = 0.5 * (lo + hi)
+    lo, up = jax.lax.fori_loop(0, iters, body, (half * eps * eps, half))
+    tau = sgn * jnp.sqrt(lo) * jnp.sqrt(up)
     if defl is not None:
-        roots = jnp.where(defl, d, roots)
-    return roots
+        org = jnp.where(defl, d, org)
+        tau = jnp.where(defl, 0.0, tau)
+    return org, tau
 
 
 def _cluster_merge(d: Array, z: Array, tol: Array):
@@ -122,9 +198,12 @@ def _cluster_merge(d: Array, z: Array, tol: Array):
     wreck the Cauchy eigenvector columns (the near-zero cluster that mean-
     centering + near-duplicate points create on every real dataset).  For
     each run of near-equal poles, a Householder reflector H (block-diagonal
-    over runs) rotates the run's z-mass into its LAST element; the others
-    become exactly zero and deflate.  Replacing D by H D H ≈ D errs by at
-    most the run width ≤ tol — the standard LAPACK trade.
+    over runs) rotates the run's z-mass into its member of largest |z|;
+    the others become exactly zero and deflate.  Replacing D by H D H ≈ D
+    moves each small mass by at most the run width while the dominant
+    direction keeps its own pole — the error weighting of LAPACK's
+    |t·c·s| ≤ tol test (a large mass rotated onto a neighbouring pole
+    would instead shift the extreme root by the whole run width).
 
     Returns (z_new, apply, fired) where apply(X) = H @ X in O(M²) via
     segment sums (no extra matmul: the paper's 2m³-per-update flop count is
@@ -140,12 +219,16 @@ def _cluster_merge(d: Array, z: Array, tol: Array):
     seg_size = jax.ops.segment_sum(ones, seg, num_segments=M)[seg]
     z2sum = jax.ops.segment_sum(z * z, seg, num_segments=M)[seg]
     znorm_seg = jnp.sqrt(z2sum)
-    is_last = jnp.concatenate([new_seg[1:], jnp.ones((1,), bool)])
-    z_last = jax.ops.segment_sum(jnp.where(is_last, z, 0.0), seg,
+    az = jnp.abs(z)
+    idx = jnp.arange(M)
+    big = az == jax.ops.segment_max(az, seg, num_segments=M)[seg]
+    keep = idx == jax.ops.segment_min(jnp.where(big, idx, M), seg,
+                                      num_segments=M)[seg]
+    z_keep = jax.ops.segment_sum(jnp.where(keep, z, 0.0), seg,
                                  num_segments=M)[seg]
-    sl = jnp.where(z_last >= 0, 1.0, -1.0)
-    target = -sl * znorm_seg                  # H z_run = target · e_last
-    w = z - jnp.where(is_last, target, 0.0)
+    sl = jnp.where(z_keep >= 0, 1.0, -1.0)
+    target = -sl * znorm_seg                  # H z_run = target · e_keep
+    w = z - jnp.where(keep, target, 0.0)
     wnorm2 = jax.ops.segment_sum(w * w, seg, num_segments=M)[seg]
     tiny = jnp.finfo(d.dtype).tiny
     active = (seg_size > 1.5) & (wnorm2 > tiny)
@@ -157,40 +240,49 @@ def _cluster_merge(d: Array, z: Array, tol: Array):
 
     wz = jax.ops.segment_sum(w * z, seg, num_segments=M)[seg]
     z_new = z - coef * w * wz
-    # exact zeros on merged (non-last) members so deflation catches them
-    z_new = jnp.where(active & ~is_last, 0.0, z_new)
+    # exact zeros on merged (non-kept) members so deflation catches them
+    z_new = jnp.where(active & ~keep, 0.0, z_new)
     return z_new, apply, jnp.any(active)
 
 
-def _gu_zhat(d: Array, roots: Array, sigma: Array, z: Array) -> Array:
+def _gu_zhat(d: Array, org: Array, tau: Array, sigma: Array,
+             z: Array) -> Array:
     """Gu–Eisenstat recomputation of |z| from the computed roots.
 
-    sigma * ẑ_i^2 = prod_j (roots_j - d_i) / prod_{j != i} (d_j - d_i).
-    Evaluated in log space (signs cancel pairwise under interlacing).
-    Inactive entries (roots_j == d_j exactly) contribute log(1) = 0 to both
-    products, so padding is transparent; the i-th numerator factor makes
-    ẑ_i = 0 exactly for deflated/inactive entries.
+    sigma * ẑ_i^2 = (root_i - d_i) * prod_{j != i} (root_j - d_i)/(d_j - d_i).
+
+    Roots arrive pole-relative (root_j = org_j + tau_j, see
+    ``_secular_bisect``), so every root_j - d_i keeps its relative
+    accuracy, and the product is a sum of the logs of the RATIOS: far
+    from i a ratio is close to 1 and its log (``_log``) carries an
+    absolute error of order eps·|log| — summing the logs of numerators
+    and denominators apart would carry eps·|log d| per term and cost the
+    eigenvectors their orthogonality at large M.  Under interlacing every
+    ratio is positive for a non-deflated i.  Deflated/inactive entries
+    (root_j == d_j exactly) contribute a ratio of 1 to every other
+    product and make their own ẑ_i = 0 exactly; coincident poles (a run
+    the cluster merge left with one live member) contribute 1 as well.
     """
-    num = roots[None, :] - d[:, None]                      # (i, j)
-    den = d[None, :] - d[:, None]
-    den = den.at[jnp.diag_indices(d.shape[0])].set(1.0)
     tiny = jnp.finfo(d.dtype).tiny
-    log_z2 = (jnp.sum(jnp.log(jnp.abs(num) + tiny), axis=1)
-              - jnp.sum(jnp.log(jnp.abs(den) + tiny), axis=1)
-              - jnp.log(jnp.abs(sigma)))
-    z2hat = jnp.exp(log_z2)
-    zhat = jnp.sign(z) * jnp.sqrt(z2hat)
+    num = (org[None, :] - d[:, None]) + tau[None, :]       # root_j - d_i
+    den = d[None, :] - d[:, None]                          # d_j - d_i
+    eye = jnp.eye(d.shape[0], dtype=bool)
+    skip = ~eye & (den == 0.0)
+    ratio = jnp.where(eye, num, num / jnp.where(eye | skip, 1.0, den))
+    terms = jnp.where(skip, 0.0, _log(jnp.abs(ratio) + tiny))
+    log_z2 = jnp.sum(terms, axis=1) - jnp.log(jnp.abs(sigma))
+    zhat = jnp.sign(z) * _exp(0.5 * log_z2)
     # Guard: if the identity degenerates numerically, fall back to z.
     ok = jnp.isfinite(zhat)
     return jnp.where(ok, zhat, z)
 
 
-def _cauchy_W(d: Array, roots: Array, zhat: Array) -> tuple[Array, Array]:
-    """W[i, j] = zhat_i / (d_i - roots_j) and per-column inverse norms."""
-    den = d[:, None] - roots[None, :]
-    eps = _eps_for(d.dtype)
-    safe = jnp.where(jnp.abs(den) < eps, jnp.where(den < 0, -eps, eps), den)
-    W = zhat[:, None] / safe
+def _cauchy_W(d: Array, org: Array, tau: Array, zhat: Array
+              ) -> tuple[Array, Array]:
+    """W[i, j] = zhat_i / (d_i - root_j) and per-column inverse norms,
+    with root_j = org_j + tau_j (pole-relative, see ``_secular_bisect``)."""
+    den = _safe_den((d[:, None] - org[None, :]) - tau[None, :])
+    W = zhat[:, None] / den
     norms = jnp.sqrt(jnp.sum(W * W, axis=0))
     inv = jnp.where(norms > 0, 1.0 / norms, 1.0)
     return W, inv
@@ -216,7 +308,7 @@ def _update_body(L: Array, U: Array, v: Array, sigma: Array, m: Array, *,
 
     if z is None:
         v = jnp.where(mask, v, 0.0)
-        z = U.T @ v
+        z = jnp.matmul(U.T, v, precision=MATMUL_PRECISION)
     else:
         z = jnp.where(mask, z, 0.0)
     # Deflation (Bunch §4, the case the paper handles by exclusion in §5):
@@ -286,16 +378,21 @@ def rank_one_update(
 class _Factor(NamedTuple):
     """One solved rank-one update as an original-domain Cauchy factor.
 
-    The normalized eigenvector rotation is W[k, j] = z_k·inv_j/(d_k-lam_j)
-    with deflated columns replaced by identity columns; ``L_new`` is the
-    updated (pre-sort) spectrum.  All vectors live in the original domain
-    (the sigma<0 flip's sign is folded into z), so the active region is a
-    prefix regardless of sigma's sign.
+    The normalized eigenvector rotation is
+    W[k, j] = z_k·inv_j/((d_k - lam_j) - tau_j) with deflated columns
+    replaced by identity columns: each new eigenvalue is lam_j + tau_j,
+    ``lam`` the old pole nearer to it and ``tau`` its offset from that
+    pole (kept apart so the distances stay accurate, see
+    ``_secular_bisect``).  ``L_new`` is the updated (pre-sort) spectrum.
+    All vectors live in the original domain (the sigma<0 flip's sign is
+    folded into z), so the active region is a prefix regardless of
+    sigma's sign.
     """
 
     z: Array
     d: Array
     lam: Array
+    tau: Array
     inv: Array
     defl: Array
     L_new: Array
@@ -310,11 +407,12 @@ def _solve_factor(d_sent: Array, z: Array, sigma: Array, m: Array,
     ``rank_one_update_pair`` — the deflation thresholds, the sigma<0 flip
     identity, and the precise/x64 solve-dtype policy live only here.
 
-    Displacement-based deflation (the LAPACK criterion): if an eigenvalue
-    moves by less than the representable resolution of the spectrum
-    (σ·z_i² ≲ eps·‖A‖), bisection collapses the root ONTO the pole and two
-    eigenvector columns degenerate to the same basis vector — deflate
-    instead (root pinned at the pole, column = e_i, brackets skip it).
+    Deflation (the LAPACK dlaed2 criterion): a component whose coupling
+    to the update is below the representable resolution of the spectrum,
+    σ·‖z‖·|z_i| ≲ eps·‖A‖, leaves its eigenpair in place to working
+    accuracy — deflate it (root pinned at the pole, column = e_i,
+    brackets skip it).  Components above that keep their root, however
+    close to the pole: the pole-relative solve resolves it.
 
     The secular solve is O(M²) VPU work but numerically delicate (pole
     differences d_i - t_j); when ``precise`` and x64 is enabled it runs in
@@ -335,7 +433,7 @@ def _solve_factor(d_sent: Array, z: Array, sigma: Array, m: Array,
     znorm = jnp.sqrt(jnp.sum(z * z))
     floor = 32.0 * _eps_for(dtype) * jnp.maximum(znorm, _eps_for(dtype))
     defl = (~mask | (jnp.abs(z) < floor)
-            | (sig_abs * z * z < 64.0 * _eps_for(dtype) * scale))
+            | (sig_abs * znorm * jnp.abs(z) < 8.0 * _eps_for(dtype) * scale))
     z = jnp.where(defl, 0.0, z)
 
     d_eff = jnp.where(neg, -d_sent[::-1], d_sent)
@@ -346,22 +444,24 @@ def _solve_factor(d_sent: Array, z: Array, sigma: Array, m: Array,
     d_s = d_eff.astype(solve_dtype)
     z_s = z_eff.astype(solve_dtype)
     sig_s = sig_abs.astype(solve_dtype)
-    roots_eff = _secular_bisect(d_s, z_s * z_s, sig_s, iters, defl=defl_eff)
+    org_eff, tau_eff = _secular_bisect(d_s, z_s * z_s, sig_s, iters,
+                                       defl=defl_eff)
     if method == "gu":
-        zhat_eff = _gu_zhat(d_s, roots_eff, sig_s, z_s)
+        zhat_eff = _gu_zhat(d_s, org_eff, tau_eff, sig_s, z_s)
         zhat_eff = jnp.where(defl_eff, 0.0, zhat_eff)
     else:
         zhat_eff = z_s
-    _, inv_eff = _cauchy_W(d_s, roots_eff, zhat_eff)
+    _, inv_eff = _cauchy_W(d_s, org_eff, tau_eff, zhat_eff)
     inv_eff = jnp.where(defl_eff, 1.0, inv_eff)
 
     z_o = jnp.where(neg, -zhat_eff[::-1], zhat_eff)
-    lam_o = jnp.where(neg, -roots_eff[::-1], roots_eff)
+    lam_o = jnp.where(neg, -org_eff[::-1], org_eff)
+    tau_o = jnp.where(neg, -tau_eff[::-1], tau_eff)
     inv_o = jnp.where(neg, inv_eff[::-1], inv_eff)
-    L_new = jnp.where(mask, lam_o.astype(dtype), d_sent)
+    L_new = jnp.where(mask, (lam_o + tau_o).astype(dtype), d_sent)
     return _Factor(z=jnp.where(mask, z_o, 0.0),
-                   d=d_sent.astype(solve_dtype), lam=lam_o, inv=inv_o,
-                   defl=defl, L_new=L_new)
+                   d=d_sent.astype(solve_dtype), lam=lam_o, tau=tau_o,
+                   inv=inv_o, defl=defl, L_new=L_new)
 
 
 def _apply_factor(U: Array, f: _Factor, mask: Array, m: Array, *,
@@ -387,16 +487,18 @@ def _apply_factor(U: Array, f: _Factor, mask: Array, m: Array, *,
         z_k = jnp.where(mask, f.z.astype(dtype), 0.0)
         d_k = jnp.where(mask, f.d.astype(dtype), 2e30)
         lam_k = jnp.where(mask, f.lam.astype(dtype), 1e30)
+        tau_k = jnp.where(mask, f.tau.astype(dtype), 0.0)
         inv_k = jnp.where(mask, f.inv.astype(dtype), 0.0)
-        C = _ops.rotate_vectors(U, z_k, d_k, lam_k, inv_k, m, row_offset)
+        C = _ops.rotate_vectors(U, z_k, d_k, lam_k, inv_k, m, row_offset,
+                                tau=tau_k)
         # f.defl ⊇ ~mask (inactive entries always deflate), so this also
         # restores the pruned inactive columns — which are the block's own
         # rows of identity columns by invariant.
         return jnp.where(f.defl[None, :], U, C)
     from repro.kernels.eigvec_update.ref import cauchy_factor_ref
-    Wn = cauchy_factor_ref(f.z, f.d, f.lam, f.inv,
-                           f.defl.astype(f.z.dtype)).astype(dtype)
-    return U @ Wn
+    Wn = cauchy_factor_ref(f.z, f.d, f.lam, f.inv, f.defl.astype(f.z.dtype),
+                           tau=f.tau).astype(dtype)
+    return jnp.matmul(U, Wn, precision=MATMUL_PRECISION)
 
 
 def _pair_factor(L: Array, z: Array, sigma: Array, m: Array, *, iters: int,
@@ -424,9 +526,7 @@ def _factor_tmatvec(f: _Factor, y: Array) -> Array:
     """(Ŵn)ᵀ y in O(M²) from the factor's vectors — never materializes U's
     rotation, which is what lets the second secular solve run before the
     first eigenvector rotation has happened."""
-    eps = _eps_for(f.z.dtype)
-    den = f.d[:, None] - f.lam[None, :]
-    den = jnp.where(jnp.abs(den) < eps, jnp.where(den < 0, -eps, eps), den)
+    den = _safe_den((f.d[:, None] - f.lam[None, :]) - f.tau[None, :])
     s = jnp.sum((f.z * y)[:, None] / den, axis=0) * f.inv
     return jnp.where(f.defl, y, s)
 
@@ -434,7 +534,7 @@ def _factor_tmatvec(f: _Factor, y: Array) -> Array:
 class _PairFactors(NamedTuple):
     """Both solved factors of a fused ±sigma pair.
 
-    Factor 1's columns carry the inter-update sort (lam1/inv1/defl1 are
+    Factor 1's columns carry the inter-update sort (lam1/tau1/inv1/defl1 are
     already permuted; cid1 records the permutation so deflated columns
     become e_{cid1[j]}).  ``L_new`` is the post-update-2 spectrum before
     the final ``perm2`` sort; ``merge_fired`` flags that a dlaed2
@@ -446,12 +546,14 @@ class _PairFactors(NamedTuple):
     z1: Array
     d1: Array
     lam1: Array
+    tau1: Array
     inv1: Array
     defl1: Array
     cid1: Array
     z2: Array
     d2: Array
     lam2: Array
+    tau2: Array
     inv2: Array
     defl2: Array
     cid2: Array
@@ -503,8 +605,10 @@ def _pair_solve(L: Array, z1: Array, sigma1: Array, z2_raw: Array,
     cid1 = perm1.astype(jnp.int32)
     cid2 = jnp.arange(M, dtype=jnp.int32)
     return _PairFactors(z1=f1.z, d1=f1.d, lam1=f1.lam[perm1],
-                        inv1=f1.inv[perm1], defl1=f1.defl[perm1], cid1=cid1,
-                        z2=f2.z, d2=f2.d, lam2=f2.lam, inv2=f2.inv,
+                        tau1=f1.tau[perm1], inv1=f1.inv[perm1],
+                        defl1=f1.defl[perm1], cid1=cid1,
+                        z2=f2.z, d2=f2.d, lam2=f2.lam, tau2=f2.tau,
+                        inv2=f2.inv,
                         defl2=f2.defl, cid2=cid2, L_new=f2.L_new,
                         perm2=perm2, merge_fired=fired)
 
@@ -533,18 +637,20 @@ def _pair_rotate_block(U: Array, pf: _PairFactors, m: Array, *,
             pf.inv1.astype(dtype), pf.defl1.astype(dtype), pf.cid1,
             pf.z2.astype(dtype), pf.d2.astype(dtype), pf.lam2.astype(dtype),
             pf.inv2.astype(dtype), pf.defl2.astype(dtype), pf.cid2,
-            m, row_offset)
+            m, row_offset, tau1=pf.tau1.astype(dtype),
+            tau2=pf.tau2.astype(dtype))
         mask = active_mask(M, m)
         C = jnp.where(mask[None, :], C, U)
     else:
         from repro.kernels.eigvec_update.ref import cauchy_factor_ref
         W1 = cauchy_factor_ref(pf.z1, pf.d1, pf.lam1, pf.inv1,
-                               pf.defl1.astype(pf.z1.dtype),
-                               pf.cid1).astype(dtype)
+                               pf.defl1.astype(pf.z1.dtype), pf.cid1,
+                               tau=pf.tau1).astype(dtype)
         W2 = cauchy_factor_ref(pf.z2, pf.d2, pf.lam2, pf.inv2,
-                               pf.defl2.astype(pf.z2.dtype),
-                               pf.cid2).astype(dtype)
-        C = (U @ W1) @ W2
+                               pf.defl2.astype(pf.z2.dtype), pf.cid2,
+                               tau=pf.tau2).astype(dtype)
+        C = jnp.matmul(jnp.matmul(U, W1, precision=MATMUL_PRECISION), W2,
+                       precision=MATMUL_PRECISION)
     return C[:, pf.perm2]
 
 
@@ -600,7 +706,9 @@ def rank_one_update_pair(
     v2 = jnp.where(mask, v2, 0.0)
 
     if z1 is None:
-        Z = U.T @ jnp.stack([v1, v2], axis=1)   # one pass over U for both z
+        # one pass over U for both z
+        Z = jnp.matmul(U.T, jnp.stack([v1, v2], axis=1),
+                       precision=MATMUL_PRECISION)
         z1, z2 = Z[:, 0], Z[:, 1]
     else:
         z1 = jnp.where(mask, z1, 0.0)
@@ -658,6 +766,6 @@ def reconstruct(L: Array, U: Array, m: Array) -> Array:
     M = L.shape[0]
     mask = active_mask(M, m)
     Lm = jnp.where(mask, L, 0.0)
-    K = (U * Lm[None, :]) @ U.T
+    K = jnp.matmul(U * Lm[None, :], U.T, precision=MATMUL_PRECISION)
     blk = mask[:, None] & mask[None, :]
     return jnp.where(blk, K, 0.0)

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kernels_fn as kf, rankone
+from repro.core.precision import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -95,7 +96,9 @@ def _transform_fields(state, *, n_components: int, adjusted: bool):
     mf = state.m.astype(state.L.dtype)
     return s_mat, AffineCorrection(mf=mf,
                                    colsum=jnp.sum(s_mat, axis=0),
-                                   colproj=(state.K1 / mf) @ s_mat,
+                                   colproj=jnp.matmul(
+                                       state.K1 / mf, s_mat,
+                                       precision=MATMUL_PRECISION),
                                    grand=state.S / mf**2)
 
 
@@ -161,7 +164,7 @@ def query(snap: ServingSnapshot, xq: Array, *, spec: kf.KernelSpec,
         kq = kf.gram_block(xq.astype(snap.X.dtype), snap.X, spec=spec)
         mask = rankone.active_mask(snap.X.shape[0], snap.m)
         kq = jnp.where(mask[None, :], kq, 0.0)
-        y = kq @ snap.S
+        y = jnp.matmul(kq, snap.S, precision=MATMUL_PRECISION)
         rs = jnp.sum(kq, axis=1)
     if snap.affine is not None:
         aff = snap.affine

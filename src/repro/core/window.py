@@ -64,9 +64,9 @@ def init_window(x0: Array, capacity: int, spec: kf.KernelSpec, *,
     kpca = inkpca.init_state(x0, capacity, spec, adjusted=adjusted,
                              dtype=dtype)
     m0 = x0.shape[0]
-    ages = jnp.zeros((capacity,), jnp.int64)     # realized: int32 w/o x64
-    ages = jnp.full((capacity,), age_sentinel(ages.dtype), ages.dtype)
-    ages = ages.at[:m0].set(jnp.arange(m0, dtype=ages.dtype))
+    dtype = jax.dtypes.canonicalize_dtype(jnp.int64)   # int32 without x64
+    ages = jnp.full((capacity,), age_sentinel(dtype), dtype)
+    ages = ages.at[:m0].set(jnp.arange(m0, dtype=dtype))
     return WindowState(kpca=kpca, ages=ages,
                        clock=jnp.asarray(m0, ages.dtype))
 

@@ -80,9 +80,8 @@ def pipeline_apply(stage_fn: Callable[[Any, Array], Array],
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params,
                              is_leaf=lambda l: hasattr(l, "shape")),
                 P())
-    from repro.distributed.sharding import shard_map
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                   check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                       check_vma=False)
     del other
     return fn(stage_params, x)
 

@@ -5,10 +5,10 @@ maps them to mesh axes.  Rules are installed by the launcher for the chosen
 mesh, so the same model code serves 1-device smoke tests (no rules -> no-op)
 and the 512-chip production mesh.
 
-Also exports ``shard_map``: a version-guarded dispatch to the JAX shard_map
-API, which moved from ``jax.experimental.shard_map`` (kwarg ``check_rep``)
-to top-level ``jax.shard_map`` (kwarg ``check_vma``).  All call sites in
-this repo go through the wrapper so either JAX generation works.
+Also exports ``make_mesh``, the one constructor of device meshes in this
+repo: every axis is ``AxisType.Auto`` (``jax.make_mesh`` defaults to
+``Explicit`` axes, under which ``with_sharding_constraint`` and the
+sharded matmuls of the model and KPCA paths are rejected).
 """
 from __future__ import annotations
 
@@ -16,26 +16,18 @@ import threading
 from contextlib import contextmanager
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _state = threading.local()
 
 
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-    _CHECK_KWARG = "check_vma"
-else:  # older JAX: experimental API with the check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _CHECK_KWARG = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """Portable shard_map: maps ``check_vma`` onto this JAX's spelling."""
-    kwargs = {}
-    if check_vma is not None:
-        kwargs[_CHECK_KWARG] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (sharding
+    propagates as in GSPMD; constraints and ``shard_map`` may name any
+    axis).  ``devices`` defaults to the first prod(shape) devices."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 DEFAULT_RULES: dict[str, tuple[str, ...] | str | None] = {
@@ -145,10 +137,6 @@ def logical_to_spec(names: tuple[str | None, ...],
             if axes is not None and shape is not None and mesh is not None:
                 if shape[i] % _axis_size(mesh, axes) != 0:
                     axes = None
-        # normalize 1-tuples to the bare axis name: older PartitionSpec
-        # compares ('model',) != 'model'
-        if isinstance(axes, tuple) and len(axes) == 1:
-            axes = axes[0]
         out.append(axes)
     return P(*out)
 

@@ -58,6 +58,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.precision import MATMUL_PRECISION
+
 DEFAULT_BLOCK = 128
 NPROJ = 8    # projected column count of ``eigvec_project`` (v padded to 8)
 
@@ -87,8 +89,8 @@ def _clamp(t, lim):
     return jnp.minimum(t, jnp.maximum(lim - 1, 0))
 
 
-def _kernel(g_ref, u_ref, z_ref, d_ref, lam_ref, inv_ref, out_ref, acc_ref,
-            *, k_steps: int):
+def _kernel(g_ref, u_ref, z_ref, d_ref, lam_ref, tau_ref, inv_ref, out_ref,
+            acc_ref, *, k_steps: int):
     i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     gr, gc = g_ref[0], g_ref[1]
     active = (i < gr) & (j < gc)
@@ -103,8 +105,15 @@ def _kernel(g_ref, u_ref, z_ref, d_ref, lam_ref, inv_ref, out_ref, acc_ref,
         zcol = z_ref[...]            # (BK, 1)
         dcol = d_ref[...]            # (BK, 1)
         lamrow = lam_ref[...]        # (1, BJ)
-        w = zcol / (dcol - lamrow)   # (BK, BJ) — Cauchy tile, never hits HBM
-        acc_ref[...] += jnp.dot(u_ref[...], w,
+        # (BK, BJ) Cauchy tile, never hits HBM; the root is lam + tau.
+        # A distance below the smallest normal number is flushed to zero
+        # on a TPU; nudge it as ``_w_tile`` does, or 0/0 poisons the tile.
+        den = (dcol - lamrow) - tau_ref[...]
+        tiny = jnp.finfo(den.dtype).tiny
+        den = jnp.where(jnp.abs(den) < tiny,
+                        jnp.where(den < 0, -tiny, tiny), den)
+        w = zcol / den
+        acc_ref[...] += jnp.dot(u_ref[...], w, precision=MATMUL_PRECISION,
                                 preferred_element_type=acc_ref.dtype)
 
     @pl.when(k == k_steps - 1)
@@ -118,10 +127,15 @@ def _kernel(g_ref, u_ref, z_ref, d_ref, lam_ref, inv_ref, out_ref, acc_ref,
 def eigvec_rotate(u: jax.Array, zhat: jax.Array, d: jax.Array,
                   lam: jax.Array, inv: jax.Array,
                   num_active: jax.Array | None = None,
-                  row_offset: jax.Array | None = None, *,
+                  row_offset: jax.Array | None = None,
+                  tau: jax.Array | None = None, *,
                   block: int = DEFAULT_BLOCK,
                   interpret: bool = False) -> jax.Array:
-    """C[i, j] = sum_k U[i,k] * zhat[k]/(d[k]-lam[j]) * inv[j].
+    """C[i, j] = sum_k U[i,k] * zhat[k]/((d[k]-lam[j])-tau[j]) * inv[j].
+
+    The root of column j is lam[j] + tau[j]: ``rankone`` passes the pole
+    nearer to the root as lam and the offset as tau (default 0), so the
+    distance keeps its relative accuracy however close the root is.
 
     u: (R, M) — a row block of the eigenvector matrix (R == M for the
     single-device square case); zhat, d, lam, inv: (M,).  Both dims are
@@ -144,14 +158,18 @@ def eigvec_rotate(u: jax.Array, zhat: jax.Array, d: jax.Array,
     dtype = u.dtype
     if pad_r or pad_c:
         u = jnp.pad(u, ((0, pad_r), (0, pad_c)))
+    if tau is None:
+        tau = jnp.zeros_like(lam)
     if pad_c:
         zhat = jnp.pad(zhat, (0, pad_c))
         d = jnp.pad(d, (0, pad_c), constant_values=2e30)
         lam = jnp.pad(lam, (0, pad_c), constant_values=1e30)
+        tau = jnp.pad(tau, (0, pad_c))
         inv = jnp.pad(inv, (0, pad_c))
     zcol = zhat.reshape(Mp, 1).astype(dtype)
     dcol = d.reshape(Mp, 1).astype(dtype)
     lamrow = lam.reshape(1, Mp).astype(dtype)
+    taurow = tau.reshape(1, Mp).astype(dtype)
     invrow = inv.reshape(1, Mp).astype(dtype)
 
     steps_r = Rp // block
@@ -172,6 +190,7 @@ def eigvec_rotate(u: jax.Array, zhat: jax.Array, d: jax.Array,
             pl.BlockSpec((block, 1), lambda i, j, k, g: (_clamp(k, g[1]), 0)),
             pl.BlockSpec((1, block), lambda i, j, k, g: (0, _clamp(j, g[1]))),
             pl.BlockSpec((1, block), lambda i, j, k, g: (0, _clamp(j, g[1]))),
+            pl.BlockSpec((1, block), lambda i, j, k, g: (0, _clamp(j, g[1]))),
         ],
         out_specs=pl.BlockSpec((block, block), lambda i, j, k, g: (i, j)),
         scratch_shapes=[pltpu.VMEM((block, block), acc_dtype)],
@@ -181,7 +200,7 @@ def eigvec_rotate(u: jax.Array, zhat: jax.Array, d: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Rp, Mp), dtype),
         interpret=interpret,
-    )(g, u, zcol, dcol, lamrow, invrow)
+    )(g, u, zcol, dcol, lamrow, taurow, invrow)
     return out[:R, :M]
 
 
@@ -203,7 +222,7 @@ def _proj_kernel(g_ref, u_ref, v_ref, out_ref, acc_ref, *, r_steps: int,
         v = jnp.where(rows < m, v_ref[...].astype(acc_ref.dtype), 0.0)
         acc_ref[...] += jax.lax.dot_general(
             u_ref[...].astype(acc_ref.dtype), v, (((0,), (0,)), ((), ())),
-            preferred_element_type=acc_ref.dtype)
+            precision=MATMUL_PRECISION, preferred_element_type=acc_ref.dtype)
 
     @pl.when(i == r_steps - 1)
     def _done():
@@ -278,11 +297,12 @@ def eigvec_project(u: jax.Array, v: jax.Array,
     return out[:M, :C]
 
 
-def _w_tile(z_ref, d_ref, lam_ref, inv_ref, defl_ref, cid_ref, k, l, *,
-            block: int, eps: float):
+def _w_tile(z_ref, d_ref, lam_ref, tau_ref, inv_ref, defl_ref, cid_ref, k,
+            l, *, block: int, tiny: float):
     """(block, block) tile (k, l) of a normalized Cauchy factor.
 
-    w[r, c] = defl[c] ? (row_r == cid[c]) : z[r] * inv[c] / (d[r] - lam[c])
+    w[r, c] = defl[c] ? (row_r == cid[c])
+                      : z[r] * inv[c] / ((d[r] - lam[c]) - tau[c])
     with r/c the in-tile offsets of global rows k·B+r, columns l·B+c.
     (W's row space is the eigenvector COLUMN index, so this is independent
     of any row-blocking of U.)
@@ -292,21 +312,24 @@ def _w_tile(z_ref, d_ref, lam_ref, inv_ref, defl_ref, cid_ref, k, l, *,
     z = z_ref[rs, :]                     # (block, 1)
     d = d_ref[rs, :]                     # (block, 1)
     lam = lam_ref[:, cs]                 # (1, block)
+    tau = tau_ref[:, cs]                 # (1, block)
     inv = inv_ref[:, cs]                 # (1, block)
     defl = defl_ref[:, cs]               # (1, block) float 0/1
     cid = cid_ref[:, cs]                 # (1, block) int32
-    den = d - lam
-    den = jnp.where(jnp.abs(den) < eps,
-                    jnp.where(den < 0, -eps, eps), den)
+    den = (d - lam) - tau
+    den = jnp.where(jnp.abs(den) < tiny,
+                    jnp.where(den < 0, -tiny, tiny), den)
     w = z * inv / den
     rows = k * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
     return jnp.where(defl > 0, (rows == cid).astype(w.dtype), w)
 
 
 def _kernel2(g_ref, u_ref,
-             z1_ref, d1_ref, lam1_ref, inv1_ref, defl1_ref, cid1_ref,
-             z2_ref, d2_ref, lam2_ref, inv2_ref, defl2_ref, cid2_ref,
-             out_ref, t_ref, *, k_steps: int, block: int, eps: float):
+             z1_ref, d1_ref, lam1_ref, tau1_ref, inv1_ref, defl1_ref,
+             cid1_ref,
+             z2_ref, d2_ref, lam2_ref, tau2_ref, inv2_ref, defl2_ref,
+             cid2_ref,
+             out_ref, t_ref, *, k_steps: int, block: int, tiny: float):
     i, k = pl.program_id(0), pl.program_id(1)
     gr, gc = g_ref[0], g_ref[1]
 
@@ -321,10 +344,10 @@ def _kernel2(g_ref, u_ref,
         u_blk = u_ref[...]                               # (block, block)
 
         def body1(l, carry):
-            w1 = _w_tile(z1_ref, d1_ref, lam1_ref, inv1_ref, defl1_ref,
-                         cid1_ref, k, l, block=block, eps=eps)
+            w1 = _w_tile(z1_ref, d1_ref, lam1_ref, tau1_ref, inv1_ref,
+                         defl1_ref, cid1_ref, k, l, block=block, tiny=tiny)
             sl = pl.dslice(l * block, block)
-            t_ref[:, sl] += jnp.dot(u_blk, w1,
+            t_ref[:, sl] += jnp.dot(u_blk, w1, precision=MATMUL_PRECISION,
                                     preferred_element_type=t_ref.dtype)
             return carry
 
@@ -340,11 +363,12 @@ def _kernel2(g_ref, u_ref,
         def _second():
             def body2(j, carry):
                 def inner(l, acc):
-                    w2 = _w_tile(z2_ref, d2_ref, lam2_ref, inv2_ref,
-                                 defl2_ref, cid2_ref, l, j, block=block,
-                                 eps=eps)
+                    w2 = _w_tile(z2_ref, d2_ref, lam2_ref, tau2_ref,
+                                 inv2_ref, defl2_ref, cid2_ref, l, j,
+                                 block=block, tiny=tiny)
                     t_blk = t_ref[:, pl.dslice(l * block, block)]
                     return acc + jnp.dot(t_blk, w2.astype(t_ref.dtype),
+                                         precision=MATMUL_PRECISION,
                                          preferred_element_type=t_ref.dtype)
 
                 acc0 = jnp.zeros((block, block), t_ref.dtype)
@@ -363,12 +387,15 @@ def eigvec_rotate2(u: jax.Array,
                    z2: jax.Array, d2: jax.Array, lam2: jax.Array,
                    inv2: jax.Array, defl2: jax.Array, cid2: jax.Array,
                    num_active: jax.Array | None = None,
-                   row_offset: jax.Array | None = None, *,
+                   row_offset: jax.Array | None = None,
+                   tau1: jax.Array | None = None,
+                   tau2: jax.Array | None = None, *,
                    block: int = DEFAULT_BLOCK,
                    interpret: bool = False) -> jax.Array:
     """Fused double rotation  C = U @ W1n @ W2n  in one pass over U.
 
-    Each factor is W[k, j] = z[k]·inv[j]/(d[k]-lam[j]), except deflated
+    Each factor is W[k, j] = z[k]·inv[j]/((d[k]-lam[j])-tau[j]) (root
+    lam + tau, tau defaulting to 0; see ``eigvec_rotate``), except deflated
     columns (defl[j] != 0) which are identity columns e_{cid[j]} — cid
     carries the sort permutation applied between the two updates.  ``u``
     may be a rectangular (R, M) row block (``row_offset`` = first global
@@ -384,8 +411,11 @@ def eigvec_rotate2(u: jax.Array,
     dtype = u.dtype
     if pad_r or pad_c:
         u = jnp.pad(u, ((0, pad_r), (0, pad_c)))
+    tau1 = jnp.zeros_like(lam1) if tau1 is None else tau1
+    tau2 = jnp.zeros_like(lam2) if tau2 is None else tau2
     if pad_c:
         z1, z2 = (jnp.pad(v, (0, pad_c)) for v in (z1, z2))
+        tau1, tau2 = (jnp.pad(v, (0, pad_c)) for v in (tau1, tau2))
         d1, d2 = (jnp.pad(v, (0, pad_c), constant_values=2e30)
                   for v in (d1, d2))
         lam1, lam2 = (jnp.pad(v, (0, pad_c), constant_values=1e30)
@@ -410,6 +440,7 @@ def eigvec_rotate2(u: jax.Array,
         pl.BlockSpec((Mp, 1), lambda i, k, g: (0, 0)),   # z
         pl.BlockSpec((Mp, 1), lambda i, k, g: (0, 0)),   # d
         pl.BlockSpec((1, Mp), lambda i, k, g: (0, 0)),   # lam
+        pl.BlockSpec((1, Mp), lambda i, k, g: (0, 0)),   # tau
         pl.BlockSpec((1, Mp), lambda i, k, g: (0, 0)),   # inv
         pl.BlockSpec((1, Mp), lambda i, k, g: (0, 0)),   # defl
         pl.BlockSpec((1, Mp), lambda i, k, g: (0, 0)),   # cid
@@ -424,15 +455,15 @@ def eigvec_rotate2(u: jax.Array,
         out_specs=pl.BlockSpec((block, Mp), lambda i, k, g: (i, 0)),
         scratch_shapes=[pltpu.VMEM((block, Mp), acc_dtype)],
     )
-    eps = float(jnp.finfo(dtype).eps)
+    tiny = float(jnp.finfo(dtype).tiny)
     out = pl.pallas_call(
-        functools.partial(_kernel2, k_steps=steps, block=block, eps=eps),
+        functools.partial(_kernel2, k_steps=steps, block=block, tiny=tiny),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Rp, Mp), dtype),
         interpret=interpret,
     )(g, u,
-      col(z1), col(d1), row(lam1), row(inv1), row(defl1),
+      col(z1), col(d1), row(lam1), row(tau1), row(inv1), row(defl1),
       row(cid1, jnp.int32),
-      col(z2), col(d2), row(lam2), row(inv2), row(defl2),
+      col(z2), col(d2), row(lam2), row(tau2), row(inv2), row(defl2),
       row(cid2, jnp.int32))
     return out[:R, :M]

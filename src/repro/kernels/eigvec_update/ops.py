@@ -1,47 +1,31 @@
 """Jit'd public wrappers for the fused eigenvector rotation kernels.
 
-Dispatch: real TPU -> compiled Pallas; CPU (this container) -> Pallas
-interpret mode for small sizes in tests, pure-jnp oracle otherwise (the
-interpreter is Python-slow; numerics are identical).
+Dispatch (``repro.kernels.dispatch.route``): a TPU runs the compiled
+Pallas kernels, any other backend the pure-jnp oracles; CPU tests run the
+kernel bodies in interpret mode through ``REPRO_PALLAS_FORCE=interpret``
+(the interpreter is Python-slow; numerics are identical).
 """
 from __future__ import annotations
 
-import os
-
 import jax
-import jax.numpy as jnp
 
+from repro.kernels.dispatch import route as _route
 from repro.kernels.eigvec_update.eigvec_update import (eigvec_project,
                                                        eigvec_rotate,
                                                        eigvec_rotate2)
 from repro.kernels.eigvec_update.ref import (eigvec_project_ref,
                                              eigvec_rotate2_ref,
                                              eigvec_rotate_ref)
-from repro.obs.hub import note_kernel_dispatch
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _force(force: str | None) -> str | None:
-    return force or os.environ.get("REPRO_PALLAS_FORCE") or None
-
-
-def _route(force: str | None) -> str:
-    if force == "ref" or (force is None and not _on_tpu()):
-        return "ref"
-    if force == "interpret":
-        return "interpret"
-    return "pallas"
 
 
 def rotate_vectors(u: jax.Array, zhat: jax.Array, d: jax.Array,
                    lam: jax.Array, inv: jax.Array,
                    num_active: jax.Array | None = None,
                    row_offset: jax.Array | None = None, *,
+                   tau: jax.Array | None = None,
                    force: str | None = None) -> jax.Array:
-    """C = U @ (diag-normalized Cauchy factor).
+    """C = U @ (diag-normalized Cauchy factor); column j's root is
+    lam_j + tau_j (``tau`` defaults to 0, see ``rankone._Factor``).
 
     ``u`` may be square (M, M) or a rectangular (R, M) row block whose
     first global row is ``row_offset`` (the distributed row-sharded
@@ -53,17 +37,11 @@ def rotate_vectors(u: jax.Array, zhat: jax.Array, d: jax.Array,
     REPRO_PALLAS_FORCE env var does the same (tests set it to 'interpret'
     so the real kernel body executes on CPU).
     """
-    route = _route(_force(force))
-    note_kernel_dispatch("eigvec_rotate", route)
+    route = _route("eigvec_rotate", force, u, zhat, d, lam, inv)
     if route == "ref":
-        return eigvec_rotate_ref(u, zhat, d, lam, inv)
-    if route == "interpret":
-        # Re-enable jit locally: pallas_call's interpret impl recurses
-        # forever under an ambient jax.disable_jit() on this JAX version.
-        with jax.disable_jit(False):
-            return eigvec_rotate(u, zhat, d, lam, inv, num_active,
-                                 row_offset, interpret=True)
-    return eigvec_rotate(u, zhat, d, lam, inv, num_active, row_offset)
+        return eigvec_rotate_ref(u, zhat, d, lam, inv, tau)
+    return eigvec_rotate(u, zhat, d, lam, inv, num_active, row_offset, tau,
+                         interpret=route == "interpret")
 
 
 def rotate_vectors2(u: jax.Array,
@@ -73,6 +51,8 @@ def rotate_vectors2(u: jax.Array,
                     inv2: jax.Array, defl2: jax.Array, cid2: jax.Array,
                     num_active: jax.Array | None = None,
                     row_offset: jax.Array | None = None, *,
+                    tau1: jax.Array | None = None,
+                    tau2: jax.Array | None = None,
                     force: str | None = None) -> jax.Array:
     """Fused double rotation C = U @ W1n @ W2n (eq. (2)/(3) back-to-back).
 
@@ -80,17 +60,13 @@ def rotate_vectors2(u: jax.Array,
     Deflated columns are generated as identity columns e_{cid[j]} inside
     the kernel, so the intermediate U @ W1n never exists in HBM.
     """
-    route = _route(_force(force))
-    note_kernel_dispatch("eigvec_rotate2", route)
     args = (u, z1, d1, lam1, inv1, defl1, cid1,
             z2, d2, lam2, inv2, defl2, cid2)
+    route = _route("eigvec_rotate2", force, *args)
     if route == "ref":
-        return eigvec_rotate2_ref(*args)
-    if route == "interpret":
-        with jax.disable_jit(False):
-            return eigvec_rotate2(*args, num_active, row_offset,
-                                  interpret=True)
-    return eigvec_rotate2(*args, num_active, row_offset)
+        return eigvec_rotate2_ref(*args, tau1, tau2)
+    return eigvec_rotate2(*args, num_active, row_offset, tau1, tau2,
+                          interpret=route == "interpret")
 
 
 def project_vectors(u: jax.Array, v: jax.Array,
@@ -105,18 +81,9 @@ def project_vectors(u: jax.Array, v: jax.Array,
     pruned output rows (>= the active tile range) come back as exact
     zeros, their true value.  Row-sharded callers psum the partials.
     """
-    route = _route(_force(force))
-    note_kernel_dispatch("eigvec_project", route)
+    route = _route("eigvec_project", force, u, v)
     if route == "ref":
         return eigvec_project_ref(u, v, num_active, row_offset)
     if route == "interpret":
-        with jax.disable_jit(False):
-            return eigvec_project(u, v, num_active, row_offset,
-                                  interpret=True)
+        return eigvec_project(u, v, num_active, row_offset, interpret=True)
     return eigvec_project(u, v, num_active, row_offset)
-
-
-def rotate(u: jax.Array, wn: jax.Array) -> jax.Array:
-    """Fallback entry used by rankone when only the dense factor is at hand
-    (keeps the pallas code-path selectable end-to-end)."""
-    return u @ wn

@@ -2,16 +2,26 @@
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import MATMUL_PRECISION
+
 
 def eigvec_rotate_ref(u: jax.Array, zhat: jax.Array, d: jax.Array,
-                      lam: jax.Array, inv: jax.Array) -> jax.Array:
+                      lam: jax.Array, inv: jax.Array,
+                      tau: jax.Array | None = None) -> jax.Array:
     """Materialize W then matmul — the unfused baseline the kernel beats.
 
     ``u`` may be square (M, M) or a rectangular (R, M) row block; the
-    product is over u's columns either way.
+    product is over u's columns either way.  Column j's root is
+    lam_j + tau_j (tau defaults to 0).
     """
-    W = zhat[:, None] / (d[:, None] - lam[None, :])
-    return (u @ W) * inv[None, :]
+    if tau is None:
+        tau = jnp.zeros_like(lam)
+    tiny = jnp.finfo(zhat.dtype).tiny
+    den = (d[:, None] - lam[None, :]) - tau[None, :]
+    den = jnp.where(jnp.abs(den) < tiny, jnp.where(den < 0, -tiny, tiny),
+                    den)
+    W = zhat[:, None] / den
+    return jnp.matmul(u, W, precision=MATMUL_PRECISION) * inv[None, :]
 
 
 def eigvec_project_ref(u: jax.Array, v: jax.Array,
@@ -24,7 +34,7 @@ def eigvec_project_ref(u: jax.Array, v: jax.Array,
         r0 = 0 if row_offset is None else row_offset
         rows = r0 + jnp.arange(u.shape[0])
         v = jnp.where((rows < num_active)[:, None], v, 0.0)
-    return u.T @ v
+    return jnp.matmul(u.T, v, precision=MATMUL_PRECISION)
 
 
 def pruned_region_mask(R: int, M: int, m, row_offset=None, *,
@@ -50,17 +60,22 @@ def pruned_region_mask(R: int, M: int, m, row_offset=None, *,
 
 def cauchy_factor_ref(z: jax.Array, d: jax.Array, lam: jax.Array,
                       inv: jax.Array, defl: jax.Array | None = None,
-                      cid: jax.Array | None = None) -> jax.Array:
+                      cid: jax.Array | None = None,
+                      tau: jax.Array | None = None) -> jax.Array:
     """Dense normalized Cauchy factor with deflated identity columns.
 
-    W[k, j] = z[k]·inv[j]/(d[k]-lam[j]); columns with defl[j] != 0 are
-    replaced by e_{cid[j]} (cid defaults to j).  Matches the in-VMEM tile
-    generation of ``eigvec_rotate2`` including its eps denominator guard.
+    W[k, j] = z[k]·inv[j]/((d[k]-lam[j])-tau[j]) (tau defaults to 0);
+    columns with defl[j] != 0 are replaced by e_{cid[j]} (cid defaults to
+    j).  Matches the in-VMEM tile generation of ``eigvec_rotate2``
+    including its denominator guard (exact zeros only).
     """
     M = z.shape[0]
-    eps = jnp.finfo(z.dtype).eps
-    den = d[:, None] - lam[None, :]
-    den = jnp.where(jnp.abs(den) < eps, jnp.where(den < 0, -eps, eps), den)
+    tiny = jnp.finfo(z.dtype).tiny
+    if tau is None:
+        tau = jnp.zeros_like(lam)
+    den = (d[:, None] - lam[None, :]) - tau[None, :]
+    den = jnp.where(jnp.abs(den) < tiny, jnp.where(den < 0, -tiny, tiny),
+                    den)
     W = z[:, None] * inv[None, :] / den
     if defl is None:
         return W
@@ -75,8 +90,10 @@ def eigvec_rotate2_ref(u: jax.Array,
                        inv1: jax.Array, defl1: jax.Array, cid1: jax.Array,
                        z2: jax.Array, d2: jax.Array, lam2: jax.Array,
                        inv2: jax.Array, defl2: jax.Array,
-                       cid2: jax.Array) -> jax.Array:
+                       cid2: jax.Array, tau1: jax.Array | None = None,
+                       tau2: jax.Array | None = None) -> jax.Array:
     """Two sequential dense rotations — the oracle for ``eigvec_rotate2``."""
-    W1 = cauchy_factor_ref(z1, d1, lam1, inv1, defl1, cid1)
-    W2 = cauchy_factor_ref(z2, d2, lam2, inv2, defl2, cid2)
-    return (u @ W1) @ W2
+    W1 = cauchy_factor_ref(z1, d1, lam1, inv1, defl1, cid1, tau=tau1)
+    W2 = cauchy_factor_ref(z2, d2, lam2, inv2, defl2, cid2, tau=tau2)
+    return jnp.matmul(jnp.matmul(u, W1, precision=MATMUL_PRECISION), W2,
+                      precision=MATMUL_PRECISION)

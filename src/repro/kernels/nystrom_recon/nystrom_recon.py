@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.precision import MATMUL_PRECISION
+
 DEFAULT_BLOCK = 128
 
 
@@ -28,7 +30,7 @@ def _kernel(bi_ref, bj_ref, s_ref, out_ref, acc_ref, *, k_steps: int):
     left = bi_ref[...] * s_ref[...]          # fuse diag(s) into the tile
     acc_ref[...] += jax.lax.dot_general(
         left, bj_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=MATMUL_PRECISION, preferred_element_type=jnp.float32)
 
     @pl.when(k == k_steps - 1)
     def _done():

@@ -1,32 +1,20 @@
 """Jit'd public wrapper for the Nyström reconstruction kernel."""
 from __future__ import annotations
 
-import os
-
 import jax
 
+from repro.kernels.dispatch import route as _route
 from repro.kernels.nystrom_recon.nystrom_recon import scaled_gram as _pallas
 from repro.kernels.nystrom_recon.ref import (scaled_gram_ref,
                                              transform_project_ref)
 from repro.kernels.nystrom_recon.transform_batch import \
     transform_project as _tb_pallas
 from repro.kernels.rbf_gram.krow_fused import PALLAS_KERNELS
-from repro.obs.hub import note_kernel_dispatch
-
-
-def _route(force: str | None) -> str:
-    force = force or os.environ.get("REPRO_PALLAS_FORCE") or None
-    if force == "ref" or (force is None and jax.default_backend() != "tpu"):
-        return "ref"
-    if force == "interpret":
-        return "interpret"
-    return "pallas"
 
 
 def scaled_gram(b: jax.Array, s: jax.Array, *, force: str | None = None
                 ) -> jax.Array:
-    route = _route(force)
-    note_kernel_dispatch("scaled_gram", route)
+    route = _route("scaled_gram", force, b, s)
     if route == "ref":
         return scaled_gram_ref(b, s)
     if route == "interpret":
@@ -42,8 +30,7 @@ def transform_project(xq: jax.Array, x: jax.Array, s: jax.Array,
     ``transform_batch.py``."""
     if spec.name not in PALLAS_KERNELS:
         force = "ref"    # non-stationary kernels: reference epilogue only
-    route = _route(force)
-    note_kernel_dispatch("transform_project", route)
+    route = _route("transform_project", force, xq, x, s)
     if route == "ref":
         return transform_project_ref(xq, x, s, num_active, spec=spec)
     if route == "interpret":

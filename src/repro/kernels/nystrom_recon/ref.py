@@ -3,10 +3,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kernels_fn as kf
+from repro.core.precision import MATMUL_PRECISION
 
 
 def scaled_gram_ref(b: jax.Array, s: jax.Array) -> jax.Array:
-    return (b * s[None, :]) @ b.T
+    return jnp.matmul(b * s[None, :], b.T, precision=MATMUL_PRECISION)
 
 
 def transform_project_ref(xq: jax.Array, x: jax.Array, s: jax.Array,
@@ -17,4 +18,4 @@ def transform_project_ref(xq: jax.Array, x: jax.Array, s: jax.Array,
     kq = kf.gram_block(xq.astype(dtype), x.astype(dtype), spec=spec)
     mask = jnp.arange(x.shape[0]) < num_active
     kq = jnp.where(mask[None, :], kq, 0.0).astype(dtype)
-    return kq @ s, jnp.sum(kq, axis=1)
+    return jnp.matmul(kq, s, precision=MATMUL_PRECISION), jnp.sum(kq, axis=1)

@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import kernels_fn as kf
+from repro.core.precision import MATMUL_PRECISION
 from repro.kernels.rbf_gram.krow_fused import _clamp, kernel_epilogue
 
 DEFAULT_BLOCK = 128
@@ -50,7 +51,7 @@ def _kernel(g_ref, xq_ref, x_ref, xn_ref, qn_ref, s_ref, y_ref, rs_ref,
     def _acc():
         dot = jax.lax.dot_general(
             xq_ref[...], x_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=acc_ref.dtype)
+            precision=MATMUL_PRECISION, preferred_element_type=acc_ref.dtype)
         d2 = jnp.maximum(
             qn_ref[...] + xn_ref[...] - 2.0 * dot.astype(acc_ref.dtype), 0.0)
         kq = kernel_epilogue(d2, name=name, sigma=sigma, scale=scale)
@@ -59,7 +60,7 @@ def _kernel(g_ref, xq_ref, x_ref, xn_ref, qn_ref, s_ref, y_ref, rs_ref,
         kqm = jnp.where(cols < m, kq, 0.0)
         acc_ref[...] += jax.lax.dot_general(
             kqm, s_ref[...].astype(acc_ref.dtype), (((1,), (0,)), ((), ())),
-            preferred_element_type=acc_ref.dtype)
+            precision=MATMUL_PRECISION, preferred_element_type=acc_ref.dtype)
         rs_acc_ref[...] += jnp.sum(kqm, axis=1, keepdims=True)
 
     @pl.when(k == m_steps - 1)

@@ -34,6 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import kernels_fn as kf
+from repro.core.precision import MATMUL_PRECISION
 
 DEFAULT_BLOCK = 128
 NAUX = 8          # projected column count: kernel row + up to 7 aux columns
@@ -62,7 +63,7 @@ def _krow_tile(x_blk, xn_blk, xq, *, name: str, sigma: float, scale: float):
     """(block, 1) kernel-row tile k(x_blk, xq) — matches kernels_fn exactly."""
     qn = jnp.sum(xq * xq)
     dot = jax.lax.dot_general(
-        x_blk, xq, (((1,), (1,)), ((), ())),
+        x_blk, xq, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
         preferred_element_type=jnp.promote_types(x_blk.dtype, jnp.float32))
     d2 = jnp.maximum(xn_blk + qn - 2.0 * dot.astype(xn_blk.dtype), 0.0)
     return kernel_epilogue(d2, name=name, sigma=sigma, scale=scale)
@@ -95,7 +96,7 @@ def _kernel(g_ref, u_ref, x_ref, xn_ref, xq_ref, aux_ref, a_ref, p_ref,
                       aux_ref[...].astype(acc_ref.dtype))
         acc_ref[...] += jax.lax.dot_general(
             u_ref[...].astype(acc_ref.dtype), v, (((0,), (0,)), ((), ())),
-            preferred_element_type=acc_ref.dtype)
+            precision=MATMUL_PRECISION, preferred_element_type=acc_ref.dtype)
 
     @pl.when(i == r_steps - 1)
     def _done():

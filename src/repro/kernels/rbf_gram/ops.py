@@ -1,30 +1,18 @@
 """Jit'd public wrapper for the RBF gram kernel (dispatch as eigvec_update)."""
 from __future__ import annotations
 
-import os
-
 import jax
 
+from repro.kernels.dispatch import route as _route
 from repro.kernels.rbf_gram.krow_fused import PALLAS_KERNELS
 from repro.kernels.rbf_gram.krow_fused import krow_project as _krow_pallas
 from repro.kernels.rbf_gram.rbf_gram import rbf_gram
 from repro.kernels.rbf_gram.ref import krow_project_ref, rbf_gram_ref
-from repro.obs.hub import note_kernel_dispatch
-
-
-def _route(force: str | None) -> str:
-    force = force or os.environ.get("REPRO_PALLAS_FORCE") or None
-    if force == "ref" or (force is None and jax.default_backend() != "tpu"):
-        return "ref"
-    if force == "interpret":
-        return "interpret"
-    return "pallas"
 
 
 def gram(x: jax.Array, y: jax.Array, sigma, *, force: str | None = None
          ) -> jax.Array:
-    route = _route(force)
-    note_kernel_dispatch("rbf_gram", route)
+    route = _route("rbf_gram", force, x, y, sigma)
     if route == "ref":
         return rbf_gram_ref(x, y, sigma)
     if route == "interpret":
@@ -39,8 +27,7 @@ def krow_project(u: jax.Array, x: jax.Array, x_new: jax.Array,
     """Fused masked kernel row + projection P = U^T [a | aux]."""
     if spec.name not in PALLAS_KERNELS:
         force = "ref"    # non-stationary kernels: reference epilogue only
-    route = _route(force)
-    note_kernel_dispatch("krow_project", route)
+    route = _route("krow_project", force, u, x, x_new, aux)
     if route == "ref":
         return krow_project_ref(u, x, x_new, aux, num_active, row_offset,
                                 spec=spec)

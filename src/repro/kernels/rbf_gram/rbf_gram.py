@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.precision import MATMUL_PRECISION
+
 DEFAULT_BLOCK = 128
 
 
@@ -32,7 +34,7 @@ def _kernel(x_ref, y_ref, xn_ref, yn_ref, sig_ref, out_ref, acc_ref, *,
     # in-kernel transpose is required.
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], y_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=MATMUL_PRECISION, preferred_element_type=jnp.float32)
 
     @pl.when(k == k_steps - 1)
     def _done():

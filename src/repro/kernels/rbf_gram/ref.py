@@ -3,12 +3,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kernels_fn as kf
+from repro.core.precision import MATMUL_PRECISION
 
 
 def rbf_gram_ref(x: jax.Array, y: jax.Array, sigma: jax.Array) -> jax.Array:
     xn = jnp.sum(x * x, axis=-1)[:, None]
     yn = jnp.sum(y * y, axis=-1)[None, :]
-    d2 = jnp.maximum(xn + yn - 2.0 * (x @ y.T), 0.0)
+    xy = jnp.matmul(x, y.T, precision=MATMUL_PRECISION)
+    d2 = jnp.maximum(xn + yn - 2.0 * xy, 0.0)
     return jnp.exp(-d2 / sigma)
 
 
@@ -28,4 +30,4 @@ def krow_project_ref(u: jax.Array, x: jax.Array, x_new: jax.Array,
     a = jnp.where(rows < num_active, kr, 0.0).astype(dtype)
     auxm = jnp.where(rows[:, None] < num_active, aux.astype(dtype), 0.0)
     v = jnp.concatenate([a[:, None], auxm], axis=1)
-    return a, u.T @ v
+    return a, jnp.matmul(u.T, v, precision=MATMUL_PRECISION)

@@ -75,6 +75,7 @@ from repro import configs, obs
 from repro.data.synthetic import TokenStream
 from repro.distributed import sharding as shd
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
 
@@ -379,25 +380,20 @@ def kpca_main(args) -> dict:
     return result
 
 
-def nystrom_main(args) -> dict:
-    """Streaming Nyström landmark-lifecycle service (grow_rows mode)."""
-    import numpy as np
+def nystrom_lifecycle(engine, state, xs, *, budget: int, rule, hub,
+                      quarantine: bool = False) -> dict:
+    """Stream the points ``xs`` through the Nyström landmark lifecycle:
+    each point becomes an observed row, then a landmark candidate under
+    ``engine.plan.landmark_policy`` until ``rule`` (a
+    ``nystrom.SufficientSubsetRule``) declares the subset sufficient.
 
-    from repro.core import engine as eng, kernels_fn as kf, nystrom
+    With the leverage policy a ``nystrom.TraceErrorTracker`` keeps
+    ``trace_error`` current from O(n·m) increments; it freezes with the
+    rule, so ``tracker_rows`` counts the rows it has seen.  Returns the
+    final state, the tracker, ``stopped_at`` and the quarantine count;
+    admissions are counted in ``hub`` (``landmark_total{action}``)."""
+    from repro.core import nystrom
 
-    rng = np.random.default_rng(args.seed)
-    d = args.dim
-    spec = kf.KernelSpec(name="rbf", sigma=float(d))
-    engine = eng.Engine(spec, _make_plan(args), adjusted=False)
-    x0 = jnp.asarray(rng.normal(size=(4, d)), jnp.float32)
-    state = nystrom.init_nystrom(None, x0, args.capacity, spec,
-                                 grow_rows=True)
-    rule = nystrom.SufficientSubsetRule(rel_tol=args.stop_rel_tol,
-                                        patience=args.stop_patience)
-    budget = args.landmark_budget or args.capacity - 1
-    hub = obs.fresh_hub()
-    # Landmark lifecycle counted as one labelled family in the hub; the
-    # result dict reads the counters back (single source of truth).
     admit = {k: hub.counter("landmark_total", action=k)
              for k in ("admitted", "rejected", "replaced")}
     ms = None
@@ -406,16 +402,31 @@ def nystrom_main(args) -> dict:
 
         ms = tm.init_metrics()
     n_quarantined = 0
-    quarantine = (getattr(engine.plan, "health", None) is not None
-                  and engine.plan.health.quarantine)
     stopped_at = None
-    t_total = time.time()
     leverage = engine.plan.landmark_policy == "leverage"
     # Incremental trace_error: O(n·m) per admission instead of the
     # O(n·m²) exact recompute the stopping rule used to trigger.
-    tracker = nystrom.TraceErrorTracker(state, spec) if leverage else None
-    for i in range(args.points):
-        x = jnp.asarray(rng.normal(size=(d,)), jnp.float32)
+    tracker = nystrom.TraceErrorTracker(state, engine.spec) if leverage \
+        else None
+    tracker_rows = int(state.Knm.shape[0])
+    for i, x in enumerate(xs):
+        if leverage and rule.sufficient:
+            # Admissions have stopped: the rest of the points only become
+            # observed rows, appended in ONE observe_rows call — each row
+            # count is a new Knm shape, so appending row by row would
+            # compile a new concatenation for every point.
+            rest = xs[i:]
+            bad = 0
+            if quarantine:
+                import numpy as np
+
+                bad = int((~np.isfinite(np.asarray(rest)).all(axis=1)).sum())
+                n_quarantined += bad
+                hub.inc("quarantined_total", bad)
+            state = nystrom.observe_rows(state, rest, engine.spec,
+                                         plan=engine.plan)
+            admit["rejected"].inc(rest.shape[0] - bad)
+            break
         if quarantine and not bool(jnp.isfinite(x).all()):
             # The observe_rows gate would drop the row anyway; counting
             # and skipping here keeps it out of the landmark offer too.
@@ -423,17 +434,15 @@ def nystrom_main(args) -> dict:
             hub.inc("quarantined_total")
             continue
         res = None
-        if leverage and not rule.sufficient:
+        if leverage:
             # ONE residual dispatch serves both the tracker's observe
             # increment and the admission gate below.  Once the rule has
-            # stopped admissions the tracker freezes too — the stopped
-            # regime pays zero per-point eigensystem dispatches.
-            res = float(nystrom.admission_residual(state, x, spec))
+            # stopped admissions the tracker freezes too (the branch at
+            # the top of the loop).
+            res = float(nystrom.admission_residual(state, x, engine.spec))
             tracker.observe(state, x, residual=res)
-        state = nystrom.observe_rows(state, x, spec, plan=engine.plan)
-        if leverage and rule.sufficient:
-            admit["rejected"].inc()
-            continue
+            tracker_rows += 1
+        state = nystrom.observe_rows(state, x, engine.spec, plan=engine.plan)
         prev = state
         state, action = engine.offer_landmark(state, x, budget=budget,
                                               residual=res)
@@ -452,18 +461,48 @@ def nystrom_main(args) -> dict:
                 ms = tm.note_trace_error(ms, tracker.value)
             if rule.observe(tracker.value):
                 stopped_at = i
+    if ms is not None:
+        hub.observe_metrics_state(ms, prefix="nystrom")
+    return {"state": state, "tracker": tracker, "tracker_rows": tracker_rows,
+            "stopped_at": stopped_at, "quarantined": n_quarantined,
+            "counts": {k: int(c.value) for k, c in admit.items()}}
+
+
+def nystrom_main(args) -> dict:
+    """Streaming Nyström landmark-lifecycle service (grow_rows mode)."""
+    import numpy as np
+
+    from repro.core import engine as eng, kernels_fn as kf, nystrom
+
+    rng = np.random.default_rng(args.seed)
+    d = args.dim
+    spec = kf.KernelSpec(name="rbf", sigma=float(d))
+    engine = eng.Engine(spec, _make_plan(args), adjusted=False)
+    x0 = jnp.asarray(rng.normal(size=(4, d)), jnp.float32)
+    state = nystrom.init_nystrom(None, x0, args.capacity, spec,
+                                 grow_rows=True)
+    rule = nystrom.SufficientSubsetRule(rel_tol=args.stop_rel_tol,
+                                        patience=args.stop_patience)
+    budget = args.landmark_budget or args.capacity - 1
+    hub = obs.fresh_hub()
+    quarantine = (getattr(engine.plan, "health", None) is not None
+                  and engine.plan.health.quarantine)
+    xs = jnp.asarray(rng.normal(size=(args.points, d)), jnp.float32)
+    t_total = time.time()
+    run = nystrom_lifecycle(engine, state, xs, budget=budget, rule=rule,
+                            hub=hub, quarantine=quarantine)
     t_total = time.time() - t_total
+    state, tracker, counts = run["state"], run["tracker"], run["counts"]
 
     err = float(nystrom.trace_error(state, spec))
     hub.set_gauge("trace_error", err)
     hub.set_gauge("active_m", int(state.kpca.m))
-    counts = {k: int(c.value) for k, c in admit.items()}
     result = {
         "mode": "nystrom", "policy": args.landmark_policy,
         "capacity": args.capacity, "budget": budget,
         "points": args.points, "m_final": int(state.kpca.m),
         "rows": int(state.Knm.shape[0]),
-        "trace_error": err, "stopped_at": stopped_at,
+        "trace_error": err, "stopped_at": run["stopped_at"],
         # Drift is only meaningful while the tracker was live: after the
         # stopping rule fires it freezes (rows keep arriving untracked).
         "tracker_drift": (abs(tracker.value - err)
@@ -474,14 +513,12 @@ def nystrom_main(args) -> dict:
         **counts,
     }
     if quarantine:
-        result["quarantined"] = n_quarantined
-    if ms is not None:
-        hub.observe_metrics_state(ms, prefix="nystrom")
+        result["quarantined"] = run["quarantined"]
     _export_metrics(args, hub)
     print(f"[serve/nystrom] {args.landmark_policy}: {args.points} points, "
           f"{counts['admitted']} admitted / {counts['replaced']} replaced / "
           f"{counts['rejected']} rejected -> m={result['m_final']}, "
-          f"trace err {err:.4f}, stopped_at={stopped_at}  {result}")
+          f"trace err {err:.4f}, stopped_at={run['stopped_at']}  {result}")
     return result
 
 
@@ -762,6 +799,7 @@ def main(argv=None) -> dict:
                     help="sufficient-subset rule: consecutive flat "
                          "admissions before stopping")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.metrics_port is not None:
         # Start before the mode main so the run is scrapeable live; the

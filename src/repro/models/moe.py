@@ -293,7 +293,7 @@ def _moe_ep(p: dict, cfg: ArchConfig, x: Array) -> Array | None:
             return body(x_rep.reshape(B * T, d), router_w, w_up, w_gate,
                         w_down).reshape(B, T, d)
 
-        fn = shd.shard_map(
+        fn = jax.shard_map(
             body3d, mesh=mesh,
             in_specs=(P(None, None, None),          # x replicated
                       P(),
@@ -312,7 +312,7 @@ def _moe_ep(p: dict, cfg: ArchConfig, x: Array) -> Array | None:
         return y.reshape(Bl, Tl, d)
 
     seq_axis = "model" if (n_model > 1 and T % n_model == 0) else None
-    fn = shd.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(data_axes, seq_axis, None),     # x: batch×seq split
                   P(),                              # router (replicated)

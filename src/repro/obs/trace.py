@@ -13,16 +13,7 @@ from __future__ import annotations
 
 import contextlib
 
-
-def trace_annotation(name: str):
-    """``jax.profiler.TraceAnnotation(name)`` or a null context when the
-    profiler surface is unavailable (stripped builds)."""
-    try:
-        from jax.profiler import TraceAnnotation
-
-        return TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+from jax.profiler import TraceAnnotation as trace_annotation
 
 
 @contextlib.contextmanager

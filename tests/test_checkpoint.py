@@ -9,6 +9,7 @@ import pytest
 
 from repro.checkpoint import (AsyncCheckpointer, latest_step, load_checkpoint,
                               save_checkpoint)
+from repro.distributed.sharding import make_mesh
 
 
 def _tree():
@@ -75,7 +76,7 @@ def test_elastic_reshard_load(tmp_path):
     """Checkpoint written under one sharding loads under another (here:
     single-device target with explicit sharding objects)."""
     d = str(tmp_path)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sharding = jax.sharding.NamedSharding(mesh,
                                           jax.sharding.PartitionSpec("data"))
     tree = {"w": jax.device_put(jnp.arange(8, dtype=jnp.float32), sharding)}

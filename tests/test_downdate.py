@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import engine as eng, inkpca, kernels_fn as kf, rankone
+from repro.distributed.sharding import make_mesh
 
 RNG = np.random.default_rng(11)
 SPEC = kf.KernelSpec(name="rbf", sigma=5.0)
@@ -211,7 +212,7 @@ def test_sharded_downdate_matches_local(plan):
     from repro.core import distributed as dkpca
 
     engine, st = _sharded_setup()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     ddown = dkpca.make_sharded_downdate(mesh, plan=plan)
     q = int(st.m) - 1
     a = kf.kernel_row(st.X[q], st.X, spec=SPEC)
@@ -233,7 +234,7 @@ def test_sharded_downdate_then_update_roundtrip():
     from repro.core import distributed as dkpca
 
     engine, st = _sharded_setup()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     plan = eng.UpdatePlan()
     x_new = jnp.asarray(np.random.default_rng(41).normal(size=4))
     st1 = engine.update(st, x_new)
@@ -262,7 +263,7 @@ def test_sharded_evict_arbitrary_row_matches_local(plan):
     from repro.core import distributed as dkpca
 
     engine, st = _sharded_setup()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     ev = dkpca.make_sharded_evict(mesh, plan=plan)
     for victim in (0, 3, int(st.m) - 1):
         a = kf.kernel_row(st.X[victim], st.X, spec=SPEC)
@@ -298,7 +299,7 @@ def test_sharded_window_block_matches_local_windowed_stream(dispatch):
     ws = stream.state
     assert int(ws.kpca.m) == W
     xs = jnp.asarray(rng.normal(size=(5, 4)))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     wb = dkpca.make_sharded_window_block(mesh, SPEC, plan=plan)
     L2, U2, X2, ages2, clock2 = wb(ws.kpca.L, ws.kpca.U, ws.kpca.X,
                                    ws.ages, ws.clock, xs, ws.kpca.m)
@@ -338,7 +339,7 @@ def test_sharded_window_block_rebases_near_sentinel():
                                       ws.ages + shift),
                        clock=ws.clock + shift)
     xs = jnp.asarray(rng.normal(size=(5, 4)))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     wb = dkpca.make_sharded_window_block(mesh, SPEC, plan=eng.UpdatePlan())
     L2, U2, X2, ages2, clock2 = wb(aged.kpca.L, aged.kpca.U, aged.kpca.X,
                                    aged.ages, aged.clock, xs, aged.kpca.m)

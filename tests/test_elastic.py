@@ -17,9 +17,10 @@ def test_reshard_4_to_2_devices(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_checkpoint, load_checkpoint
+        from repro.distributed.sharding import make_mesh
 
         d = {str(tmp_path)!r}
-        mesh4 = jax.make_mesh((4,), ("data",))
+        mesh4 = make_mesh((4,), ("data",))
         sh4 = NamedSharding(mesh4, P("data"))
         tree = {{"w": jax.device_put(jnp.arange(16, dtype=jnp.float32), sh4),
                  "b": jax.device_put(jnp.ones((4, 8), jnp.bfloat16),
@@ -27,7 +28,7 @@ def test_reshard_4_to_2_devices(tmp_path):
         save_checkpoint(d, 1, tree)
 
         # restore onto a 2-device mesh (simulating shrink-after-failure)
-        mesh2 = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("data",))
+        mesh2 = make_mesh((2,), ("data",), devices=jax.devices()[:2])
         sh2 = NamedSharding(mesh2, P("data"))
         target = {{"w": jax.ShapeDtypeStruct((16,), jnp.float32,
                                              sharding=sh2),

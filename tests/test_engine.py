@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import engine as eng, inkpca, kernels_fn as kf, rankone
+from repro.distributed.sharding import make_mesh
 
 RNG = np.random.default_rng(17)
 SPEC = kf.KernelSpec(name="rbf", sigma=5.0)
@@ -453,7 +454,7 @@ def test_sharded_bucketed_update_full_capacity_state():
     L = jnp.asarray(np.sort(lam))
     U = jnp.asarray(vec)
     v = jnp.asarray(rng.normal(size=M))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     upd = dkpca.make_sharded_update(
         mesh, plan=eng.UpdatePlan(dispatch="bucketed", min_bucket=8))
     Ls, Us = upd(L, U, v, jnp.float64(1.7), jnp.int32(M))

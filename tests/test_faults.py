@@ -222,7 +222,8 @@ ws = stream.state
 clean = jnp.asarray(rng.normal(size=(5, 4)))
 bad = np.array(clean)
 bad = np.insert(bad, 2, faults.nan_point(4).astype(np.float64), axis=0)
-mesh = jax.make_mesh((2,), ("data",))
+from repro.distributed.sharding import make_mesh
+mesh = make_mesh((2,), ("data",))
 plan = eng.UpdatePlan(fuse_krow=True, matmul="jnp2",
                       health=hl.DEFAULT_POLICY)
 wb = dkpca.make_sharded_window_block(mesh, SPEC, plan=plan)

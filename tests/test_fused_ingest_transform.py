@@ -316,7 +316,8 @@ for i in range(4, 12):
     stream.update(jnp.asarray(X[i]))
 ws = stream.state
 xs = jnp.asarray(rng.normal(size=(5, 4)))
-mesh = jax.make_mesh((2,), ("data",))
+from repro.distributed.sharding import make_mesh
+mesh = make_mesh((2,), ("data",))
 errs = {}
 for tag, plan in (("fixed", eng.UpdatePlan(fuse_krow=True, matmul="jnp2")),
                   ("bucketed", eng.UpdatePlan(dispatch="bucketed",

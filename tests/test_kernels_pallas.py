@@ -369,3 +369,43 @@ def test_eigvec_rotate_used_in_rank_one_update():
                                    np.abs(np.asarray(Ub[:m, :m])), atol=1e-3)
     finally:
         os.environ["REPRO_PALLAS_FORCE"] = "ref"
+
+
+def test_rotation_kernels_take_pole_relative_roots():
+    """Roots passed as (pole, offset): the kernels form the distance as
+    (d - lam) - tau, exactly as the dense oracles do."""
+    from repro.kernels.eigvec_update.eigvec_update import eigvec_rotate2
+    from repro.kernels.eigvec_update.ref import eigvec_rotate2_ref
+
+    M, m = 200, 150
+    u, z, d, lam, inv = _padded_rotation_inputs(M, m)
+    mask = np.arange(M) < m
+    tau = jnp.asarray(np.where(mask, RNG.uniform(-1e-3, 1e-3, M), 0.0),
+                      jnp.float32)
+    out = eigvec_rotate(u, z, d, lam, inv, jnp.int32(m), None, tau,
+                        interpret=True, block=64)
+    ref = eigvec_rotate_ref(u, z, d, lam, inv, tau)
+    # The f32 sweep tolerances of the tests above.
+    np.testing.assert_allclose(np.asarray(out[:, :m]),
+                               np.asarray(ref[:, :m]), rtol=5e-3, atol=5e-3)
+    defl = jnp.asarray(~mask, jnp.float32)
+    cid = jnp.arange(M, dtype=jnp.int32)
+    args = (z, d, lam, inv, defl, cid, z, d, lam + 0.5, inv, defl, cid)
+    out2 = eigvec_rotate2(u, *args, jnp.int32(m), None, tau, -tau,
+                          interpret=True, block=64)
+    ref2 = eigvec_rotate2_ref(u, *args, tau, -tau)
+    np.testing.assert_allclose(np.asarray(out2[:, :m]),
+                               np.asarray(ref2[:, :m]), rtol=5e-3, atol=5e-3)
+
+
+def test_pallas_route_refuses_64_bit_operands():
+    """The compiled TPU kernels are 32-bit: a float64 state on the
+    ``pallas`` route raises a clear error instead of reaching the TPU
+    compiler."""
+    from repro.kernels.eigvec_update import ops
+
+    M = 16
+    v = jnp.ones((M,), jnp.float64)
+    with pytest.raises(ValueError, match="32-bit operands"):
+        ops.rotate_vectors(jnp.eye(M, dtype=jnp.float64), v, v, v, v,
+                           force="pallas")

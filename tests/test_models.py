@@ -8,6 +8,7 @@ pytestmark = pytest.mark.slow  # model-zoo / driver integration tier
 
 from repro.models import lm, ssm, xlstm
 from repro.models.config import ArchConfig, MoEConfig
+from repro.distributed.sharding import make_mesh
 
 B, T = 2, 16
 
@@ -136,7 +137,7 @@ def test_moe_ep_equals_einsum_on_host_mesh():
         cfg, moe=dataclasses.replace(cfg.moe, impl="ep"))
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     tokens = _batch(cfg)["tokens"]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with shd.use_mesh(mesh):
         l1 = lm.forward(params, cfg, tokens, remat=False)
         l2 = lm.forward(params, cfg_ep, tokens, remat=False)

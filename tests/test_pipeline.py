@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed.pipeline import pipeline_apply
+from repro.distributed.sharding import make_mesh
 import pytest
 
 
@@ -19,7 +20,7 @@ def _stage_fn(p, x):
 
 
 def test_single_stage_identity():
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     rng = np.random.default_rng(0)
     params = {"w": jnp.asarray(rng.normal(size=(1, 8, 8)), jnp.float32)}
     x = jnp.asarray(rng.normal(size=(4, 8)), jnp.float32)
@@ -36,11 +37,12 @@ def test_multi_stage_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import pipeline_apply
+        from repro.distributed.sharding import make_mesh
 
         def stage_fn(p, x):
             return jnp.tanh(x @ p["w"])
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         rng = np.random.default_rng(0)
         params = {"w": jnp.asarray(rng.normal(size=(4, 8, 8)), jnp.float32)}
         x = jnp.asarray(rng.normal(size=(8, 8)), jnp.float32)

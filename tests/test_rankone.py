@@ -236,3 +236,50 @@ def test_pair_merge_fallback_property(seed, n_cluster, sigma):
                                atol=1e-9)
     G = np.asarray(Up[:m, :m]).T @ np.asarray(Up[:m, :m])
     assert np.abs(G - np.eye(m)).max() < 1e-8
+
+
+def test_secular_roots_resolve_offsets_below_the_pole_ulp():
+    """Each f32 root comes back as (nearer pole, offset): an offset far
+    below the pole's rounding unit keeps its relative accuracy."""
+    d = np.arange(8, dtype=np.float64) * 10.0 + 100.0    # ulp(100) ~ 8e-6
+    z = np.full(8, 0.3)
+    z[3] = 1e-3                                          # shift ~1e-6
+    sigma = 1.0
+    exact = np.linalg.eigvalsh(np.diag(d) + sigma * np.outer(z, z))
+    org, tau = rankone._secular_bisect(
+        jnp.asarray(d, jnp.float32), jnp.asarray(z * z, jnp.float32),
+        jnp.float32(sigma), 32)
+    org, tau = np.asarray(org, np.float64), np.asarray(tau, np.float64)
+    # Offsets from the poles d_j, computed without ever forming the root.
+    got = (org - d) + tau
+    want = exact - d
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    assert abs(got[3]) < 1e-5
+
+
+@pytest.mark.parametrize("sigma", [0.5, -0.5])
+def test_f32_update_of_a_centred_gram_stays_orthogonal(sigma):
+    """The mean-adjustment step of Algorithm 2: a near-constant vector on
+    a centred RBF gram, whose spectrum is a dense cluster near zero.  In
+    f32 the update keeps eigenvalues and orthogonality at working
+    accuracy (it lost both when roots collapsed onto their poles)."""
+    rng = np.random.default_rng(5)
+    W = 128
+    x = rng.normal(size=(W, 10))
+    d2 = np.sum((x[:, None] - x[None]) ** 2, axis=-1)
+    K = np.exp(-d2 / 10.0)
+    r = K.mean(axis=1)
+    Kc = K - r[:, None] - r[None, :] + r.mean()
+    lam, U = np.linalg.eigh(Kc)
+    v = 1.0 + 0.01 * rng.normal(size=W)
+    want = np.linalg.eigvalsh(Kc + sigma * np.outer(v, v))
+    L = rankone.sentinelize(jnp.asarray(lam, jnp.float32), jnp.int32(W),
+                            jnp.float32(0.0))
+    L2, U2 = rankone.rank_one_update(
+        L, jnp.asarray(U, jnp.float32), jnp.asarray(v, jnp.float32),
+        jnp.float32(sigma), jnp.int32(W), iters=32, precise=False)
+    got = np.sort(np.asarray(L2, np.float64))
+    U2 = np.asarray(U2, np.float64)
+    scale = np.abs(want).max()
+    assert np.abs(got - want).max() < 1e-5 * scale
+    assert np.abs(U2.T @ U2 - np.eye(W)).max() < 1e-5

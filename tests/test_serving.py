@@ -292,7 +292,8 @@ qt = dist.make_tenant_query(mesh2, spec, plan=plan)
 err_q = float(jnp.abs(qt(snaps, q)
                       - serving.query_batch(snaps, q, spec=spec,
                                             plan=plan)).max())
-mesh1 = jax.make_mesh((4,), ("data",))
+from repro.distributed.sharding import make_mesh
+mesh1 = make_mesh((4,), ("data",))
 bplan = plan._replace(dispatch="bucketed", min_bucket=8)
 reb = dist.make_rebalanced_update(mesh1, plan=bplan)
 full = dist.make_sharded_update(mesh1, plan=bplan)

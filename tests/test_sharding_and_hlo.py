@@ -7,11 +7,12 @@ from jax.sharding import PartitionSpec as P
 from repro.distributed import sharding as shd
 from repro.distributed.straggler import HeartbeatMonitor, StepTimer
 from repro.launch import hlo_parse
+from repro.distributed.sharding import make_mesh
 
 
 # ------------------------------------------------------------- sharding ----
 def test_logical_to_spec_divisibility_drop():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     with shd.use_mesh(mesh, rules={"heads": "model", "batch": "data"}):
         # 'data' axis absent from mesh -> dropped by use_mesh filtering
         spec = shd.logical_to_spec(("batch", "heads"), (4, 8))
@@ -19,7 +20,7 @@ def test_logical_to_spec_divisibility_drop():
 
 
 def test_logical_to_spec_dedup_axes():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     with shd.use_mesh(mesh, rules={"a": "model", "b": "model"}):
         spec = shd.logical_to_spec(("a", "b"), (4, 4))
         assert spec == P("model", None)   # first dim wins
@@ -32,7 +33,7 @@ def test_constrain_noop_without_mesh():
 
 
 def test_use_mesh_restores_state():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     assert shd.get_mesh() is None
     with shd.use_mesh(mesh):
         assert shd.get_mesh() is mesh
@@ -145,7 +146,7 @@ def test_sharded_rank_one_update_matches_local():
     L = rankone.sentinelize(jnp.asarray(L), jnp.int32(m), jnp.float64(0.0))
     v = np.zeros(M); v[:m] = rng.normal(size=m)
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     upd = dkpca.make_sharded_update(mesh)
     Ls, Us = upd(jnp.asarray(L), jnp.asarray(U), jnp.asarray(v),
                  jnp.float64(1.7), jnp.int32(m))
@@ -172,7 +173,7 @@ def test_sharded_pair_update_matches_local_pair():
     v1 = np.zeros(M); v1[:m] = rng.normal(size=m)
     v2 = np.zeros(M); v2[:m] = rng.normal(size=m)
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     pair = dkpca.make_sharded_update_pair(mesh, plan=eng.UpdatePlan())
     Ls, Us = pair(jnp.asarray(L), jnp.asarray(U), jnp.asarray(v1),
                   jnp.float64(1.7), jnp.asarray(v2), jnp.float64(-1.7),
@@ -221,7 +222,7 @@ def test_sharded_pair_fallback_matches_two_single_updates_clustered():
     assert bool(rankone._merge_fires(L, U.T @ jnp.asarray(v1),
                                      jnp.float64(1.7), jnp.int32(m)))
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     pair = dkpca.make_sharded_update_pair(
         mesh, plan=eng.UpdatePlan(merge_fallback=True))
     Lp, Up = pair(L, U, jnp.asarray(v1), jnp.float64(1.7), jnp.asarray(v2),
@@ -254,7 +255,7 @@ def test_sharded_bucketed_update_matches_local():
     v = np.zeros(M); v[:m] = rng.normal(size=m)
     v = jnp.asarray(v)
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     upd = dkpca.make_sharded_update(
         mesh, plan=eng.UpdatePlan(dispatch="bucketed", min_bucket=16))
     Ls, Us = upd(L, U, v, jnp.float64(1.7), jnp.int32(m))
@@ -310,7 +311,8 @@ U = jnp.asarray(U)
 v1 = np.zeros(M); v1[:m] = rng.normal(size=m)
 v2 = np.zeros(M); v2[:m] = rng.normal(size=m)
 v1, v2 = jnp.asarray(v1), jnp.asarray(v2)
-mesh = jax.make_mesh((2,), ("data",))
+from repro.distributed.sharding import make_mesh
+mesh = make_mesh((2,), ("data",))
 upd = dkpca.make_sharded_update(
     mesh, plan=eng.UpdatePlan(dispatch="bucketed", min_bucket=16))
 Ls, Us = upd(L, U, v1, jnp.float64(1.7), jnp.int32(m))
@@ -371,7 +373,8 @@ st = inkpca.init_state(jnp.asarray(X[:4]), 16, SPEC, adjusted=False,
                        dtype=jnp.float64)
 for i in range(4, 11):
     st = engine.update(st, jnp.asarray(X[i]))
-mesh = jax.make_mesh((2,), ("data",))
+from repro.distributed.sharding import make_mesh
+mesh = make_mesh((2,), ("data",))
 errs = {}
 ev = dkpca.make_sharded_evict(
     mesh, plan=eng.UpdatePlan(dispatch="bucketed", min_bucket=8))

@@ -263,7 +263,8 @@ ws = stream.state
 xs = np.asarray(rng.normal(size=(6, 4)))
 xs[2] = np.nan
 xs = jnp.asarray(xs)
-mesh = jax.make_mesh((2,), ("data",))
+from repro.distributed.sharding import make_mesh
+mesh = make_mesh((2,), ("data",))
 plan = eng.UpdatePlan(health=hl.DEFAULT_POLICY)
 wb = dkpca.make_sharded_window_block(mesh, SPEC, plan=plan)
 wbm = dkpca.make_sharded_window_block_metered(mesh, SPEC, plan=plan)

@@ -223,7 +223,8 @@ ws = stream.state
 xs = np.asarray(rng.normal(size=(6, 4)))
 xs[2] = faults.nan_point(4)
 xs = jnp.asarray(xs)
-mesh = jax.make_mesh((2,), ("data",))
+from repro.distributed.sharding import make_mesh
+mesh = make_mesh((2,), ("data",))
 plan = eng.UpdatePlan(fuse_krow=True, matmul="jnp2",
                       health=hl.DEFAULT_POLICY)
 wb = dkpca.make_sharded_window_block(mesh, SPEC, plan=plan)
