@@ -6,14 +6,16 @@ a single non-finite input poisons ``U`` forever.  This module gives every
 consumer (stream, window scan, multi-tenant batch, Nyström tracker,
 sharded mesh, serving loop) three things:
 
-**In-graph probes** (``probe``) — a cheap O(M·B) sampled orthogonality
-residual, eigenvalue-negativity and non-finite flags, computed INSIDE the
-existing update/window dispatches.  A ``HealthState`` pytree rides along
-the ``KPCAState`` exactly the way the arrival ring rides ``WindowState``:
-no extra host sync, no extra dispatch.  The probe rotates through the
-active eigenvector columns (``probes`` counts dispatches and picks the
-next B columns each time), so a slowly drifting column is caught within
-ceil(m/B) dispatches while each individual probe stays O(M·B).
+**In-graph probes** (``probe``) — the exact orthogonality residual
+over every column, eigenvalue-negativity and non-finite flags, computed
+INSIDE the existing update/window dispatches.  A ``HealthState`` pytree
+rides along the ``KPCAState`` exactly the way the arrival ring rides
+``WindowState``: no extra host sync, no extra dispatch.  The residual
+costs one M³ contraction (UᵀU) per dispatch, against the ~8 M³ of every
+point a dispatch ingests: at most an eighth of a single-point dispatch,
+1/(8n) of an n-point block.  A sampled probe would be cheaper but blind:
+rounding drift is spread over all columns, and a few sampled ones can
+sit under the threshold while others are over it.
 
 **Input quarantine** (``_gate`` inside the guarded dispatches) — a
 non-finite (or, optionally, kernel-row-outlier) point is rejected BEFORE
@@ -49,6 +51,7 @@ scatter-sentinel regeneration cannot perturb a rejected step either).
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple
 
@@ -74,10 +77,7 @@ class HealthPolicy(NamedTuple):
     ``UpdatePlan.health`` as a jit-static field (like ``window`` and
     ``landmark_policy``).
 
-    probe_cols:  columns sampled per orthogonality probe (B); the probe
-                 costs O(M·B) and rotates, covering all m active columns
-                 every ceil(m/B) dispatches
-    orth_tol:    healthy-threshold on the sampled residual
+    orth_tol:    healthy-threshold on the orthogonality residual
                  max_j ‖(UᵀU − I) e_j‖₂ — crossing it is the heal trigger
     neg_tol:     relative eigenvalue-negativity tolerance: the gram (or
                  centered gram) is PSD, so min(L) < −neg_tol·max|L| flags
@@ -102,7 +102,6 @@ class HealthPolicy(NamedTuple):
                  (``launch/serve.IngestServeLoop``)
     """
 
-    probe_cols: int = 8
     orth_tol: float = 1e-3
     neg_tol: float = 1e-2
     quarantine: bool = True
@@ -119,8 +118,7 @@ class HealthState(NamedTuple):
     that rides along the eigensystem state through the guarded
     dispatches (device-resident; reading it is the caller's sync).
 
-    orth_err:      last sampled orthogonality residual
-                   max_j ‖(UᵀU − I) e_j‖₂ over the probed columns
+    orth_err:      last orthogonality residual max_j ‖(UᵀU − I) e_j‖₂
     neg_frac:      relative negativity of the most negative active
                    eigenvalue, max(0, −min L)/max|L| (0 when PSD holds)
     nonfinite:     sticky flag: 1 once any probe saw a non-finite
@@ -173,30 +171,23 @@ def probe(state, hstate: HealthState, policy: HealthPolicy,
           ref_lam: Array | None = None) -> HealthState:
     """One in-graph health probe of a KPCAState-like (L, U, m) pytree.
 
-    O(M·B) matmul + O(M) reductions: B rotating active columns are
-    checked for orthogonality against the whole basis (which also
-    catches row-support violations — an inactive row r carrying mass
-    shows up in the r-th entry of UᵀU e_j), the active spectrum for
-    negativity and non-finiteness.  Pure function of scalars-in /
+    One M³ contraction + O(M²) reductions: every column is checked for
+    orthogonality against the whole basis (which also catches
+    row-support violations — an inactive row r carrying mass shows up in
+    the r-th entry of UᵀU e_j), the active spectrum for negativity and
+    non-finiteness.  Pure function of scalars-in /
     scalars-out: safe under jit, scan and vmap, no host sync.
     """
     L, U, m = state.L, state.U, state.m
     M = L.shape[0]
     dtype = L.dtype
-    B = max(1, min(int(policy.probe_cols), M))
-    mm = jnp.maximum(m, 1)
-    idx = (hstate.probes * B + jnp.arange(B, dtype=jnp.int32)) % mm
-    cols = jnp.take(U, idx, axis=1)                      # (M, B)
-    E = (jnp.matmul(U.T, cols, precision=MATMUL_PRECISION)
-         - jax.nn.one_hot(idx, M, dtype=dtype).T)
-    orth = jnp.sqrt(jnp.max(jnp.sum(E * E, axis=0)))
+    orth = orth_residual(U)
     act = rankone.active_mask(M, m)
     Lact = jnp.where(act, L, 0.0)
     lmax = jnp.max(jnp.abs(Lact))
     tiny = jnp.asarray(jnp.finfo(dtype).tiny, dtype)
     neg = jnp.maximum(-jnp.min(Lact), 0.0) / jnp.maximum(lmax, tiny)
-    finite = (jnp.all(jnp.isfinite(Lact)) & jnp.all(jnp.isfinite(cols))
-              & jnp.isfinite(orth))
+    finite = jnp.all(jnp.isfinite(Lact)) & jnp.isfinite(orth)
     bad = (~finite).astype(jnp.int32)
     drift = (spectral_drift(state, ref_lam) if ref_lam is not None
              else hstate.spec_drift)
@@ -375,17 +366,19 @@ def _guarded_window_chunk_impl(kpca, ages: Array, clock: Array, hstate,
 
 
 # --------------------------------------------------------- heal ladder --
-def exact_orth_residual(state) -> float:
-    """Host-side EXACT orthogonality residual max_j ‖(UᵀU − I) e_j‖₂
-    over all M columns (O(M³) — heal-time only, never on the hot path).
-    Returns +inf when U holds non-finite entries."""
-    U = state.U
-    if not bool(jnp.all(jnp.isfinite(U))):
-        return float("inf")
-    M = U.shape[0]
+def orth_residual(U: Array) -> Array:
+    """Orthogonality residual max_j ‖(UᵀU − I) e_j‖₂ over all M columns
+    (traced; non-finite when U holds a non-finite entry)."""
     E = (jnp.matmul(U.T, U, precision=MATMUL_PRECISION)
-         - jnp.eye(M, dtype=U.dtype))
-    return float(jnp.sqrt(jnp.max(jnp.sum(E * E, axis=0))))
+         - jnp.eye(U.shape[0], dtype=U.dtype))
+    return jnp.sqrt(jnp.max(jnp.sum(E * E, axis=0)))
+
+
+def exact_orth_residual(state) -> float:
+    """Host-side spelling of ``orth_residual`` for a state; +inf when U
+    holds non-finite entries."""
+    r = float(orth_residual(state.U))
+    return r if math.isfinite(r) else float("inf")
 
 
 def polish(state):

@@ -58,10 +58,8 @@ def gram_eigh(K: Array) -> tuple[Array, Array]:
     GiB of host memory at the sizes a chip holds (compiling the M=2048
     one for a v5e passes 8 GiB of host memory; M=8192 ran a 40 GiB host
     out of memory), while the host decomposition of an M=8192 gram takes
-    seconds and is more accurate.  Traced callers (under jit or vmap)
-    keep ``jnp.linalg.eigh``."""
-    if isinstance(K, jax.core.Tracer):
-        return jnp.linalg.eigh(K)
+    seconds and is more accurate.  There is no traced path: under jit or
+    vmap the conversion to a host array raises."""
     lam, vec = np.linalg.eigh(np.asarray(K, np.float64))
     return jnp.asarray(lam, K.dtype), jnp.asarray(vec, K.dtype)
 

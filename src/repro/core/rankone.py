@@ -259,18 +259,23 @@ def _gu_zhat(d: Array, org: Array, tau: Array, sigma: Array,
     and denominators apart would carry eps·|log d| per term and cost the
     eigenvectors their orthogonality at large M.  Under interlacing every
     ratio is positive for a non-deflated i.  Deflated/inactive entries
-    (root_j == d_j exactly) contribute a ratio of 1 to every other
-    product and make their own ẑ_i = 0 exactly; coincident poles (a run
-    the cluster merge left with one live member) contribute 1 as well.
+    (root_j == d_j exactly) contribute a ratio of exactly 1 to every
+    other product, so they are skipped rather than divided out: a TPU's
+    f32 division returns x/x one ulp off 1 for some x, and the hundreds
+    of deflated poles of a clustered spectrum then biased every ẑ_i by
+    ~1e-5, differently for each i (2e-5 of orthogonality per update at
+    M=2048 on a v5e).  Their own ẑ_i is 0 exactly; coincident poles (a
+    run the cluster merge left with one live member) contribute 1 as well.
     """
     tiny = jnp.finfo(d.dtype).tiny
     num = (org[None, :] - d[:, None]) + tau[None, :]       # root_j - d_i
     den = d[None, :] - d[:, None]                          # d_j - d_i
     eye = jnp.eye(d.shape[0], dtype=bool)
-    skip = ~eye & (den == 0.0)
+    pinned = (org == d) & (tau == 0.0)                     # root_j == d_j
+    skip = ~eye & ((den == 0.0) | pinned[None, :])
     ratio = jnp.where(eye, num, num / jnp.where(eye | skip, 1.0, den))
     terms = jnp.where(skip, 0.0, _log(jnp.abs(ratio) + tiny))
-    log_z2 = jnp.sum(terms, axis=1) - jnp.log(jnp.abs(sigma))
+    log_z2 = jnp.sum(terms, axis=1) - _log(jnp.abs(sigma))
     zhat = jnp.sign(z) * _exp(0.5 * log_z2)
     # Guard: if the identity degenerates numerically, fall back to z.
     ok = jnp.isfinite(zhat)

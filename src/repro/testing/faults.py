@@ -97,7 +97,7 @@ def nan_point(d: int, *, kind: str = "nan", index: int = 0,
 def corrupt_eigvecs(state, *, magnitude: float = 0.1, seed: int = 0):
     """Additive gaussian damage to the ACTIVE eigenvector block — models
     slow orthogonality drift (or a partial HBM scribble) that the
-    sampled probe must detect and ``heal`` must repair.  Keeps the
+    probe must detect and ``heal`` must repair.  Keeps the
     padding invariants (only rows/cols < m are touched)."""
     import jax.numpy as jnp
 

@@ -283,3 +283,18 @@ def test_f32_update_of_a_centred_gram_stays_orthogonal(sigma):
     scale = np.abs(want).max()
     assert np.abs(got - want).max() < 1e-5 * scale
     assert np.abs(U2.T @ U2 - np.eye(W)).max() < 1e-5
+
+
+@pytest.mark.parametrize("fn", ["_gu_zhat", "_exp", "_log"])
+def test_gu_eisenstat_uses_exact_ops_only(fn):
+    """The Gu–Eisenstat z-recompute sums M log-ratios and exponentiates
+    the sum, so every step must be accurate to f32 on every backend.  A
+    TPU's f32 ``exp`` and ``log`` are off by up to ~100 and ~4000 ulps;
+    neither may appear in the traced computation."""
+    M = 16
+    d = jnp.arange(M, dtype=jnp.float32)
+    args = {"_gu_zhat": (d, d, d * 0.0 + 0.5, jnp.float32(1.0), d + 1.0),
+            "_exp": (d,), "_log": (d + 1.0,)}[fn]
+    jaxpr = str(jax.make_jaxpr(getattr(rankone, fn))(*args))
+    for prim in ("exp", "log", "exp2", "log1p", "expm1"):
+        assert f" {prim}[" not in jaxpr and f" {prim} " not in jaxpr, prim
