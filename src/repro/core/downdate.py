@@ -29,6 +29,11 @@ Pipeline for ``downdate(state, i)``:
    rotates only inside the near-degenerate eigenspace — the same
    error-versus-gap trade as the dlaed2 cluster merge in ``rankone``.
 
+Stage names (``jax.named_scope``, as in ``rankone``): ``kpca.downdate``
+the permutation to the boundary and the contraction; the boundary row is
+``kpca.krow`` and the inverse pairs keep ``rankone``'s own stages, the
+innermost name being the one that counts.
+
 Cost matches the forward update: O(M_b³) in the rotation at the active
 bucket — ``Engine.downdate`` slices to the bucket holding m, and the next
 *update* re-buckets downward automatically since bucket choice reads the
@@ -70,6 +75,7 @@ def _move_key_order(src: Array, dst: Array, M: int) -> Array:
     return jnp.argsort(key)
 
 
+@jax.named_scope("kpca.downdate")
 def permute_to_boundary(state, i: Array):
     """Apply ``boundary_perm`` to the state's row-indexed arrays."""
     order = boundary_perm(i, state.m, state.L.shape[0])
@@ -77,6 +83,7 @@ def permute_to_boundary(state, i: Array):
                           X=state.X[order])
 
 
+@jax.named_scope("kpca.downdate")
 def contract_rows(L: Array, U: Array, w: Array, m: Array, *,
                   row_ids: Array | None = None
                   ) -> tuple[Array, Array, Array]:
@@ -133,6 +140,7 @@ def contract_rows(L: Array, U: Array, w: Array, m: Array, *,
     return L, U, m_new
 
 
+@jax.named_scope("kpca.downdate")
 def contract_last(L: Array, U: Array, m: Array) -> tuple[Array, Array, Array]:
     """Remove the decoupled boundary eigenpair of the full square system
     and shrink m by one (see ``contract_rows``)."""
@@ -141,6 +149,7 @@ def contract_last(L: Array, U: Array, m: Array) -> tuple[Array, Array, Array]:
     return contract_rows(L, U, w, m)
 
 
+@jax.named_scope("kpca.krow")
 def _boundary_row(state, spec: kf.KernelSpec) -> tuple[Array, Array, Array]:
     """Kernel row of the boundary point against the survivors.
 

@@ -57,6 +57,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import kernels_fn as kf, rankone
 from repro.core.precision import MATMUL_PRECISION
 
@@ -246,6 +247,7 @@ def _scatter_stacked(full, sub):
 
 
 # ------------------------------------------------------ shared primitives --
+@jax.named_scope("kpca.krow")
 def masked_row(state, x_new: Array, spec: kf.KernelSpec
                ) -> tuple[Array, Array]:
     """Kernel row against stored points, zeroed beyond the active count."""
@@ -1771,6 +1773,7 @@ class StreamBatch:
 
             self.metrics = tm.note_drift(self.metrics, drift)
 
+    @obs.span("kpca.update")
     def update(self, xs: Array, active: Array | None = None):
         """Fold xs[i] (shape (B, d)) into tenant i, one device step per
         occupied bucket (one total for ``cohorts="max"``) — preceded, in
@@ -2056,6 +2059,7 @@ class StreamBatch:
         return {"quarantined": int(self.quarantined.sum()),
                 "quarantined_per_tenant": self.quarantined.copy()}
 
+    @obs.span("kpca.probe")
     def probe_all(self, ref_lam=None):
         """Vmapped in-graph health probe over every tenant's working
         state — no flush, one probe dispatch per occupied bucket group.
@@ -2095,6 +2099,7 @@ class StreamBatch:
             one(st, idx, idx)
         return healthy, drift
 
+    @obs.span("kpca.heal")
     def heal(self, *, level: str = "auto") -> int:
         """Walk the heal ladder (``health.heal_kpca``) over the cohort:
         probe every tenant, flush, and heal the unhealthy ones in place
@@ -2137,6 +2142,7 @@ class StreamBatch:
                 + jnp.asarray(rungs[1], jnp.int32))
         return len(todo)
 
+    @obs.span("kpca.snapshot")
     def publish(self, n_components: int | None = None):
         """Publish per-tenant ``serving.ServingSnapshot``s (stacked on the
         tenant axis) from the current working state — the decoupled-serve

@@ -212,7 +212,8 @@ def ingest_unadjusted(state: KPCAState, x_new: Array, *, spec: kf.KernelSpec,
     kn = jnp.maximum(k_new, jnp.finfo(dtype).tiny)  # sigma = 4/k guard
 
     aux = jnp.zeros((M, 0), dtype)
-    a, P = kops.krow_project(state.U, state.X, x_new, aux, m, spec=spec)
+    with jax.named_scope("kpca.krow"):
+        a, P = kops.krow_project(state.U, state.X, x_new, aux, m, spec=spec)
     p = P[:, 0]                                     # Uᵀa, pre-expansion
 
     sum_a = jnp.sum(a)
@@ -259,7 +260,8 @@ def ingest_adjusted(state: KPCAState, x_new: Array, *, spec: kf.KernelSpec,
 
     # One fused pass: a plus Uᵀ[a | 1_m | K1] (the kernel masks rows >= m).
     aux = jnp.stack([jnp.ones((M,), dtype), state.K1], axis=1)
-    a, P = kops.krow_project(state.U, state.X, x_new, aux, m, spec=spec)
+    with jax.named_scope("kpca.krow"):
+        a, P = kops.krow_project(state.U, state.X, x_new, aux, m, spec=spec)
     pa, p1, pk1 = P[:, 0], P[:, 1], P[:, 2]
 
     # --- Step 1: mean-adjustment of the existing m×m block (2 updates). ---
@@ -300,7 +302,8 @@ def ingest_adjusted(state: KPCAState, x_new: Array, *, spec: kf.KernelSpec,
     # inactive columns are identity columns on that masked region, so the
     # pruned projection is exact.
     from repro.kernels.eigvec_update import ops as eops
-    Z = eops.project_vectors(U, jnp.stack([v1, v2], axis=1), m1)
+    with jax.named_scope("kpca.krow"):
+        Z = eops.project_vectors(U, jnp.stack([v1, v2], axis=1), m1)
     L, U = eng.apply_pair(L, U, v1, sigma, v2, -sigma, m1, plan=plan,
                           z1=Z[:, 0], z2=Z[:, 1])
 

@@ -30,6 +30,13 @@ Design for TPUs / jit:
   builds W tiles in VMEM on the fly (set ``matmul="pallas"``).
 * sigma < 0 is reduced to sigma > 0 via the flip identity
   ``eig(D + s zz^T) = -rev(eig(-rev(D) + |s| rev(z)rev(z)^T))``.
+
+Stage names (``jax.named_scope``, stable: the benchmark's trace
+reduction attributes device time by them): ``kpca.krow`` the projection
+z = Uᵀv of the update vector (with the kernel row its ingest builds),
+``kpca.solve`` sentinels, cluster merge, deflation, secular bisection,
+Gu–Eisenstat ẑ and the Cauchy norms, ``kpca.rotate`` the eigenvector
+rotation U @ W and the column permutations that re-sort U.
 """
 from __future__ import annotations
 
@@ -311,41 +318,47 @@ def _update_body(L: Array, U: Array, v: Array, sigma: Array, m: Array, *,
     dtype = L.dtype
     mask = active_mask(M, m)
 
-    if z is None:
-        v = jnp.where(mask, v, 0.0)
-        z = jnp.matmul(U.T, v, precision=MATMUL_PRECISION)
-    else:
-        z = jnp.where(mask, z, 0.0)
+    with jax.named_scope("kpca.krow"):
+        if z is None:
+            v = jnp.where(mask, v, 0.0)
+            z = jnp.matmul(U.T, v, precision=MATMUL_PRECISION)
+        else:
+            z = jnp.where(mask, z, 0.0)
     # Deflation (Bunch §4, the case the paper handles by exclusion in §5):
     # eigendirections with |z_i| ~ 0 do not move — zero them out, pin their
     # roots at the poles, and skip them in every other root's bracket.
     # (Centering makes K' exactly singular along 1, and near-duplicate
     # points cluster eigenvalues near 0, so this path is exercised on every
     # real dataset, not just in corner cases.)
-    sig_abs = jnp.abs(sigma)
+    with jax.named_scope("kpca.solve"):
+        sig_abs = jnp.abs(sigma)
 
-    # Re-sentinelize with head-room for the top root's travel, then apply the
-    # flip identity so the effective sigma is positive.  Under the flip the
-    # sentinels land (negated) at the *bottom* of the array, still sorted.
-    room = sig_abs * jnp.sum(z * z)
-    d_sent = sentinelize(L, m, room)
+        # Re-sentinelize with head-room for the top root's travel, then
+        # apply the flip identity so the effective sigma is positive.
+        # Under the flip the sentinels land (negated) at the *bottom* of
+        # the array, still sorted.
+        room = sig_abs * jnp.sum(z * z)
+        d_sent = sentinelize(L, m, room)
 
-    # Cluster-merge deflation (dlaed2-style): rotate the z-mass of runs of
-    # near-equal poles into one member; U absorbs the block reflector at
-    # O(M²). Sentinels are spaced by 1 ≫ tol and never merge.
-    scale = jnp.max(jnp.abs(jnp.where(mask, L, 0.0))) + room + 1e-30
-    tol = 64.0 * _eps_for(dtype) * scale
-    z, applyH, _ = _cluster_merge(d_sent, z, tol)
-    U = applyH(U.T).T                            # U @ H, no matmul
+        # Cluster-merge deflation (dlaed2-style): rotate the z-mass of runs
+        # of near-equal poles into one member; U absorbs the block
+        # reflector at O(M²). Sentinels are spaced by 1 ≫ tol and never
+        # merge.
+        scale = jnp.max(jnp.abs(jnp.where(mask, L, 0.0))) + room + 1e-30
+        tol = 64.0 * _eps_for(dtype) * scale
+        z, applyH, _ = _cluster_merge(d_sent, z, tol)
+        U = applyH(U.T).T                        # U @ H, no matmul
 
-    f = _solve_factor(d_sent, z, sigma, m, scale, iters=iters, method=method,
-                      precise=precise)
-    U_new = _apply_factor(U, f, mask, m, matmul=matmul,
-                          row_offset=row_offset)
-    # Deflation can locally reorder roots (a root may legitimately cross a
-    # deflated pole); the next update's interlacing needs ascending order.
-    perm = jnp.argsort(f.L_new)
-    return f.L_new[perm], U_new[:, perm]
+        f = _solve_factor(d_sent, z, sigma, m, scale, iters=iters,
+                          method=method, precise=precise)
+    with jax.named_scope("kpca.rotate"):
+        U_new = _apply_factor(U, f, mask, m, matmul=matmul,
+                              row_offset=row_offset)
+        # Deflation can locally reorder roots (a root may legitimately
+        # cross a deflated pole); the next update's interlacing needs
+        # ascending order.
+        perm = jnp.argsort(f.L_new)
+        return f.L_new[perm], U_new[:, perm]
 
 
 @partial(jax.jit, static_argnames=("iters", "method", "matmul", "precise"))
@@ -581,6 +594,7 @@ def _merge_fires(L: Array, z: Array, sigma: Array, m: Array) -> Array:
     return fired
 
 
+@jax.named_scope("kpca.solve")
 def _pair_solve(L: Array, z1: Array, sigma1: Array, z2_raw: Array,
                 sigma2: Array, m: Array, *, iters: int, method: str,
                 precise: bool) -> _PairFactors:
@@ -618,6 +632,7 @@ def _pair_solve(L: Array, z1: Array, sigma1: Array, z2_raw: Array,
                         perm2=perm2, merge_fired=fired)
 
 
+@jax.named_scope("kpca.rotate")
 def _pair_rotate_block(U: Array, pf: _PairFactors, m: Array, *,
                        matmul: str, row_offset: Array | None = None
                        ) -> Array:
@@ -710,14 +725,15 @@ def rank_one_update_pair(
     v1 = jnp.where(mask, v1, 0.0)
     v2 = jnp.where(mask, v2, 0.0)
 
-    if z1 is None:
-        # one pass over U for both z
-        Z = jnp.matmul(U.T, jnp.stack([v1, v2], axis=1),
-                       precision=MATMUL_PRECISION)
-        z1, z2 = Z[:, 0], Z[:, 1]
-    else:
-        z1 = jnp.where(mask, z1, 0.0)
-        z2 = jnp.where(mask, z2, 0.0)
+    with jax.named_scope("kpca.krow"):
+        if z1 is None:
+            # one pass over U for both z
+            Z = jnp.matmul(U.T, jnp.stack([v1, v2], axis=1),
+                           precision=MATMUL_PRECISION)
+            z1, z2 = Z[:, 0], Z[:, 1]
+        else:
+            z1 = jnp.where(mask, z1, 0.0)
+            z2 = jnp.where(mask, z2, 0.0)
     pf = _pair_solve(L, z1, sigma1, z2, sigma2, m, iters=iters,
                      method=method, precise=precise)
 
@@ -763,7 +779,8 @@ def expand_eigensystem(L: Array, U: Array, lam_new: Array, m: Array
     unit eigenvector must be e_{m+1}.)
     """
     L_new, perm, m_new = expand_eigensystem_perm(L, lam_new, m)
-    return L_new, U[:, perm], m_new
+    with jax.named_scope("kpca.rotate"):
+        return L_new, U[:, perm], m_new
 
 
 def reconstruct(L: Array, U: Array, m: Array) -> Array:

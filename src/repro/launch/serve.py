@@ -169,6 +169,12 @@ class IngestServeLoop:
     Publish/heal/drift decisions are mirrored into a ``TelemetryHub``
     (``hub=``, default the process hub) and — when the plan carries the
     metric lane — into the batch's in-graph ``MetricsState``.
+
+    **Program spans** (``obs.span``, recorded on that hub): ``kpca.ingest``
+    (id ``step``, the ingest step number, shared by its children) holds
+    ``kpca.update`` and ``kpca.publish``, which holds ``kpca.probe``,
+    ``kpca.heal``, ``kpca.snapshot`` and ``kpca.ref_spectrum``;
+    ``kpca.query`` (id ``query``, the query sequence number) stands alone.
     """
 
     def __init__(self, batch, spec, *, plan=None, n_components=None,
@@ -191,6 +197,8 @@ class IngestServeLoop:
         self.ref_lam = None        # (B, C) top spectrum at last publish
         self._last_drift = 0.0     # most recent probed max drift
         self._since_probe = 0
+        self._steps = 0            # ingest steps begun (span id ``step``)
+        self._queries = 0          # queries begun (span id ``query``)
         self.snaps = batch.publish(n_components)
         self.generation = 0          # host mirror of snaps.generation
         self._since = 0
@@ -206,19 +214,22 @@ class IngestServeLoop:
         nc = int(self.n_components
                  if self.n_components is not None
                  else getattr(self.plan, "serve_components", 8))
-        self.ref_lam = jax.vmap(lambda s: hl.top_spectrum(s, nc))(st)
+        with obs.span("kpca.ref_spectrum", hub=self.hub):
+            self.ref_lam = jax.vmap(lambda s: hl.top_spectrum(s, nc))(st)
         self._last_drift = 0.0
         self._since_probe = 0
 
     def query(self, q):
         """(B, nq, d) queries against the published snapshot; safe to call
         at any point relative to ingest — snapshots are immutable."""
-        if self._query_fn is not None:
-            return self._query_fn(self.snaps, q)
         from repro.core import serving
 
-        return serving.query_batch(self.snaps, q, spec=self.spec,
-                                   plan=self.plan)
+        self._queries += 1
+        with obs.span("kpca.query", hub=self.hub, query=self._queries):
+            if self._query_fn is not None:
+                return self._query_fn(self.snaps, q)
+            return serving.query_batch(self.snaps, q, spec=self.spec,
+                                       plan=self.plan)
 
     def publish(self):
         """Republish the working state: new snapshot, host-flip the
@@ -226,37 +237,38 @@ class IngestServeLoop:
         probe verdict (heal once, then refuse — the previous snapshot
         keeps serving and ``skipped`` counts the refusal).  Returns the
         current (tenant-stacked) snapshot either way."""
-        if self.policy is not None:
-            from repro.core import health as hl
+        with obs.span("kpca.publish", hub=self.hub):
+            if self.policy is not None:
+                from repro.core import health as hl
 
-            healthy, _ = self.batch.probe_all()
-            if not healthy.all():
-                try:
-                    n = self.batch.heal()
-                    self.heals += n
-                    self.hub.inc("heals_total", n)
-                except hl.HealthError:
-                    # Stored points corrupt: in-place healing impossible.
-                    # Restore-from-checkpoint belongs to whoever owns the
-                    # checkpoint directory — degrade to stale serving.
-                    pass
                 healthy, _ = self.batch.probe_all()
-            if not healthy.all():
-                self.skipped += 1
-                self.hub.inc("skipped_publishes_total")
-                self.hub.emit({"event": "skipped_publish",
-                               "generation": self.generation})
-                self.batch.note_skipped_publish()
-                return self.snaps
-        self.snaps = self.batch.publish(self.n_components)
-        self.generation += 1
-        self.hub.inc("publishes_total")
-        self.hub.set_gauge("generation", self.generation)
-        self.hub.emit({"event": "publish", "generation": self.generation,
-                       "drift": self._last_drift})
-        self._since = 0
-        self._record_ref()
-        return self.snaps
+                if not healthy.all():
+                    try:
+                        n = self.batch.heal()
+                        self.heals += n
+                        self.hub.inc("heals_total", n)
+                    except hl.HealthError:
+                        # Stored points corrupt: in-place healing impossible.
+                        # Restore-from-checkpoint belongs to whoever owns the
+                        # checkpoint directory — degrade to stale serving.
+                        pass
+                    healthy, _ = self.batch.probe_all()
+                if not healthy.all():
+                    self.skipped += 1
+                    self.hub.inc("skipped_publishes_total")
+                    self.hub.emit({"event": "skipped_publish",
+                                   "generation": self.generation})
+                    self.batch.note_skipped_publish()
+                    return self.snaps
+            self.snaps = self.batch.publish(self.n_components)
+            self.generation += 1
+            self.hub.inc("publishes_total")
+            self.hub.set_gauge("generation", self.generation)
+            self.hub.emit({"event": "publish", "generation": self.generation,
+                           "drift": self._last_drift})
+            self._since = 0
+            self._record_ref()
+            return self.snaps
 
     def _drift_due(self) -> bool:
         """True when any tenant's spectrum has left the published one.
@@ -296,13 +308,15 @@ class IngestServeLoop:
         """Fold one (B, d) block into the working state; republish when
         the serve_every cadence — or, with ``publish_on_drift``, the
         spectral-drift trigger — says so.  True iff a publish happened."""
-        self.batch.update(xs)
-        self._since += 1
-        if not self._publish_due():
-            return False
-        gen0 = self.generation
-        self.publish()
-        return self.generation != gen0
+        self._steps += 1
+        with obs.span("kpca.ingest", hub=self.hub, step=self._steps):
+            self.batch.update(xs)
+            self._since += 1
+            if not self._publish_due():
+                return False
+            gen0 = self.generation
+            self.publish()
+            return self.generation != gen0
 
     def step(self, xs, queries=None):
         """One service step: queries first (against B), then ingest
@@ -767,9 +781,11 @@ def main(argv=None) -> dict:
                          "from a daemon thread during the run; 0 picks "
                          "an ephemeral port")
     ap.add_argument("--metrics-jsonl", default=None, metavar="PATH",
-                    help="append hub events during the run and write a "
-                         "final full-registry scrape line to PATH "
-                         "(one JSON object per line)")
+                    help="append hub events to PATH during the run; at "
+                         "exit rewrite it with what the hub's rings hold "
+                         "(the last events, then the last program spans) "
+                         "and a final full-registry scrape line (one JSON "
+                         "object per line)")
     ap.add_argument("--drift-probe-every", type=int, default=4,
                     metavar="K",
                     help="decoupled mode: run the spectral-drift probe "

@@ -2,9 +2,11 @@
 
 The in-graph half lives in ``repro.core.telemetry`` (a ``MetricsState``
 pytree riding the stream); this package is everything that happens on
-the host — the ``TelemetryHub`` registry, ``span()`` profiler tracing,
-per-kernel dispatch counters, and the Prometheus / JSONL export surface
-used by ``launch/serve.py``.
+the host — the ``TelemetryHub`` registry, the program spans of
+``span()`` (kept on the hub's span ring and annotated on the profiler's
+timeline) with the JAX traces and compiles booked to them, per-kernel
+dispatch counters, and the Prometheus / JSONL export surface used by
+``launch/serve.py``.
 """
 from repro.obs.export import (parse_prometheus, read_jsonl, serve_metrics,
                               to_prometheus, write_jsonl)

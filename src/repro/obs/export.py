@@ -6,10 +6,11 @@ Two surfaces over one ``TelemetryHub``:
   (counters/gauges/histogram summaries); ``serve_metrics(hub, port)``
   serves it on ``GET /metrics`` from a daemon thread —
   ``serve.py --metrics-port P`` wires it up.
-* ``write_jsonl(path, hub)`` dumps the buffered events plus one final
-  ``scrape`` event; ``hub.open_jsonl(path)`` streams events live
-  instead.  ``read_jsonl`` / ``parse_prometheus`` close the round trip
-  (and are what the exporter tests diff against).
+* ``write_jsonl(path, hub)`` dumps the events and program spans the
+  hub's rings still hold plus one final ``scrape`` event;
+  ``hub.open_jsonl(path)`` streams events live instead.  ``read_jsonl``
+  / ``parse_prometheus`` close the round trip (and are what the exporter
+  tests diff against).
 """
 from __future__ import annotations
 
@@ -91,12 +92,15 @@ def parse_prometheus(text: str) -> dict:
 
 # ----------------------------------------------------------------- JSONL --
 def write_jsonl(path, hub) -> None:
-    """Dump the hub's buffered events plus one final ``scrape`` event —
-    the full registry (latency summaries included), one JSON object per
+    """Dump what the hub's rings hold — its events, then its program
+    spans as ``{"event": "span", ...}`` — plus one final ``scrape`` event
+    (the full registry, latency summaries included), one JSON object per
     line."""
     with open(path, "w") as f:
-        for evt in hub.events:
+        for evt in list(hub.events):
             f.write(json.dumps(evt) + "\n")
+        for rec in list(hub.spans):
+            f.write(json.dumps({"event": "span", **rec}) + "\n")
         f.write(json.dumps({"event": "scrape", **hub.scrape()}) + "\n")
 
 

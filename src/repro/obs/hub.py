@@ -5,10 +5,12 @@ dispatch routes, publishes, drift probes), gauges (active m, drift,
 trace error — usually mirrored out of an in-graph
 ``core/telemetry.MetricsState``), latency histograms with the
 compile-vs-steady key split that used to be copy-pasted as ``_PhaseTimer``
-across ``launch/serve.py``, and a JSONL event log.  ``scrape()`` returns
-the whole registry as a flat dict; ``to_prometheus()`` renders the text
-exposition format (served by ``obs.export.serve_metrics`` under
-``serve.py --metrics-port``).
+across ``launch/serve.py``, and two bounded rings: the JSONL event log
+(``events``, the last ``EVENT_RING`` publishes, skips and heals) and the
+program spans of ``obs.trace.span`` (``spans``, the last ``SPAN_RING``).
+``scrape()`` returns the whole registry as a flat dict;
+``to_prometheus()`` renders the text exposition format (served by
+``obs.export.serve_metrics`` under ``serve.py --metrics-port``).
 
 The hub is plain host state — nothing here ever enters a jitted graph.
 Metric identity is ``name`` plus an optional label set, rendered
@@ -16,6 +18,7 @@ Prometheus-style (``kernel_dispatch_total{kernel="rbf_gram",route="ref"}``).
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import re
 import threading
@@ -24,6 +27,14 @@ import time
 from repro.obs.trace import trace_annotation
 
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_:]")
+
+# Ring sizes.  A service publishes a few times a second for as long as it
+# runs, so an unbounded log would grow for the life of the process:
+# 4096 events is over half an hour of publishes at 2/s, and 16384 spans
+# hold a few minutes of a service's steps (about six spans each) and
+# queries, more than one benchmark window.
+EVENT_RING = 4096
+SPAN_RING = 16384
 
 
 def sanitize(name: str) -> str:
@@ -135,7 +146,9 @@ class LatencyHistogram:
 
 
 class TelemetryHub:
-    """Registry of counters/gauges/histograms plus a JSONL event buffer.
+    """Registry of counters/gauges/histograms plus the event and span
+    rings (``collections.deque`` with ``maxlen`` ``EVENT_RING`` and
+    ``SPAN_RING``: the oldest entry drops out when a ring is full).
 
     Thread-safe for the registration paths (the decoupled serving loop
     and a ``--metrics-port`` scrape thread share one hub).
@@ -146,7 +159,8 @@ class TelemetryHub:
         self._counters: dict[str, Counter] = {}
         self._gauges: dict[str, Gauge] = {}
         self._hists: dict[str, LatencyHistogram] = {}
-        self.events: list[dict] = []
+        self.events: collections.deque = collections.deque(maxlen=EVENT_RING)
+        self.spans: collections.deque = collections.deque(maxlen=SPAN_RING)
         self._jsonl = None
 
     # ---- registration ----------------------------------------------------
@@ -256,6 +270,7 @@ class TelemetryHub:
             self._gauges.clear()
             self._hists.clear()
             self.events.clear()
+            self.spans.clear()
 
 
 _DEFAULT = TelemetryHub()
