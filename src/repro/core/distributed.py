@@ -59,6 +59,8 @@ unconditional, preserving the deadlock-free discipline above.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -879,17 +881,13 @@ def make_tenant_query(mesh, spec: kf.KernelSpec, *,
 
     Snapshots are immutable and per-tenant independent, so the read path
     is embarrassingly parallel: the tenant axis shards every leaf's
-    leading dim, the body vmaps ``serving.query`` over local tenants, and
-    there are ZERO collectives — query latency never rides the update
+    leading dim, the body is ``serving.query_batch`` over local tenants,
+    and there are ZERO collectives — query latency never rides the update
     path's all-reduces, which is the point of decoupled serving.
     """
     from repro.core import serving
 
-    def body(snaps, xq):
-        return jax.vmap(
-            lambda s, x: serving.query(s, x, spec=spec, plan=plan))(snaps,
-                                                                    xq)
-
+    body = partial(serving.query_batch, spec=spec, plan=plan)
     return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(tenant_axis), P(tenant_axis)),

@@ -64,13 +64,12 @@ def coefficients(state: KRRState, lam: float) -> Array:
 
 def predict(state: KRRState, x: Array, lam: float,
             spec: kf.KernelSpec) -> Array:
-    """f(x) = k(x, X) α for new points x: (n, d)."""
-    st = state.kpca
-    alpha = coefficients(state, lam)
-    K_x = kf.gram_block(x.astype(st.X.dtype), st.X, spec=spec)
-    mask = rankone.active_mask(st.X.shape[0], st.m)
-    return jnp.matmul(jnp.where(mask[None, :], K_x, 0.0), alpha,
-                      precision=MATMUL_PRECISION)
+    """f(x) = k(x, X) α for new points x: (n, d).
+
+    Publish-then-query: the compiled serving head answers against an
+    ephemeral snapshot, so a prediction from the state is bit-identical
+    to one from a snapshot published from it."""
+    return snapshot_predict(publish_predict(state, lam), x, spec)
 
 
 def publish_predict(state: KRRState, lam: float, *,

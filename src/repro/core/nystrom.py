@@ -619,21 +619,14 @@ def query_features(state: NystromState, xq: Array, n: int,
     ``nystrom_recon.transform_project`` kernel (shared with the KPCA
     batched transform) contracts each kernel tile against
     S = U diag(λ⁺) in VMEM.
+
+    Publish-then-query (``publish_features``, ``snapshot_features``): the
+    compiled serving head answers against an ephemeral snapshot, so the
+    features of a state are bit-identical to those of a snapshot
+    published from it.
     """
-    st = state.kpca
-    mask = rankone.active_mask(st.L.shape[0], st.m)
-    mf = st.m.astype(st.L.dtype)
-    s_mat = (st.U * _pinv_lam(st.L, mask)[None, :]).astype(st.X.dtype)
-    if plan is not None and plan.fuse_krow:
-        from repro.kernels.nystrom_recon import ops as nops
-        y, _ = nops.transform_project(jnp.asarray(xq), st.X, s_mat, st.m,
-                                      spec=spec)
-    else:
-        kq = kf.gram_block(jnp.asarray(xq).astype(st.X.dtype), st.X,
-                           spec=spec)
-        kq = jnp.where(mask[None, :], kq, 0.0)
-        y = jnp.matmul(kq, s_mat, precision=MATMUL_PRECISION)
-    return jnp.sqrt(mf / n) * jnp.where(mask[None, :], y, 0.0)
+    return snapshot_features(publish_features(state, n), xq, spec,
+                             plan=plan)
 
 
 def publish_features(state: NystromState, n: int, *,

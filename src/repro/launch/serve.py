@@ -221,7 +221,12 @@ class IngestServeLoop:
 
     def query(self, q):
         """(B, nq, d) queries against the published snapshot; safe to call
-        at any point relative to ingest — snapshots are immutable."""
+        at any point relative to ingest — snapshots are immutable.
+
+        One dispatch of ``serving.query_batch``'s compiled program (one per
+        spec, ``fuse_krow`` and shapes; ``q`` may be a NumPy array): the
+        first query of a shape compiles, every later one — republishes
+        included — books no JAX trace or compile to ``kpca.query``."""
         from repro.core import serving
 
         self._queries += 1

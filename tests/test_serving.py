@@ -186,6 +186,48 @@ def test_stream_batch_publish_matches_transform():
     assert list(np.asarray(sb.publish().generation)) == [1] * B
 
 
+@pytest.mark.parametrize("fuse", [False, True])
+def test_loop_queries_run_one_compiled_program(fuse):
+    """After one warm query, ``IngestServeLoop.query`` books no JAX trace
+    and no compile to ``kpca.query`` — across two republishes too — and
+    answers bit for bit as the head's per-tenant ``vmap`` of ``query``
+    and the batch's frozen transform do."""
+    from repro import obs
+    from repro.launch.serve import IngestServeLoop
+
+    rng = np.random.default_rng(8)
+    B, d = 3, 5
+    plan = eng.DEFAULT_PLAN._replace(serve_components=4, fuse_krow=fuse)
+    sb = eng.StreamBatch(jnp.asarray(rng.normal(size=(B, 4, d))), 64, SPEC,
+                         plan=plan, adjusted=True, dtype=jnp.float64)
+    hub = obs.TelemetryHub()
+    loop = IngestServeLoop(sb, SPEC, plan=plan, n_components=4, hub=hub)
+    q = rng.normal(size=(B, 6, d))            # a NumPy pool, as served
+    jax.clear_caches()                        # so the warm query is cold
+    loop.query(q)                             # warm: the one compile
+    warm = hub.scrape()
+    keys = ('jax_traces_total{span="kpca.query"}',
+            'jax_compiles_total{span="kpca.query"}')
+    assert all(warm.get(k, 0.0) >= 1.0 for k in keys)
+
+    answers = []
+    for step in range(3):
+        if step:
+            loop.ingest(rng.normal(size=(B, d)))     # publishes
+        y = loop.query(q)
+        head = jax.vmap(lambda s, x: serving.query(s, x, spec=SPEC,
+                                                   plan=plan))(loop.snaps, q)
+        assert _bits_equal(y, head)
+        assert _bits_equal(y, sb.transform(q, n_components=4))
+        answers.append(np.asarray(y))
+    assert loop.generation == 2
+    assert not _bits_equal(answers[0], answers[2])
+    after = hub.scrape()
+    assert [after.get(k, 0.0) for k in keys] == [warm[k] for k in keys]
+    spans = [r for r in hub.spans if r["name"] == "kpca.query"]
+    assert [(r["traces"], r["compiles"]) for r in spans[1:]] == [(0, 0)] * 3
+
+
 def test_project_vectors_kernel_matches_ref():
     """The rect-pruned Uᵀv projection kernel (interpret mode) matches the
     dense reference on the active block and writes exact zeros beyond it
